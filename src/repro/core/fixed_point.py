@@ -47,7 +47,7 @@ def mul_round_f32(a, b):
     """
     a = jnp.asarray(a, jnp.float32)
     b = jnp.asarray(b, jnp.float32)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         p = a.astype(jnp.float64) * b.astype(jnp.float64)
         return p.astype(jnp.float32)
 
@@ -61,6 +61,28 @@ def to_fixed(x, frac_bits: int, dtype=jnp.int32):
 
 def from_fixed(q, frac_bits: int):
     return q.astype(jnp.float32) / np.float32(1 << frac_bits)
+
+
+def fx_sum(t, axis=None):
+    """Exact sum of int32 Q-format terms ``t`` as an int32 ``(..., 2)``
+    pair ``(hi, lo)`` worth ``hi * 256 + lo`` with ``0 <= lo < 256``.
+
+    A gradient sums one term per training row, and at the paper's
+    dataset sizes it outgrows int32 (SUSY's first LIN step has a bias
+    gradient of -2.56e9).  The pair stays exact while a shard holds
+    fewer than 2^23 rows and the summed ``|t|`` stays below 2^39, and
+    pairs from many shards add element-wise without overflow — still
+    integers, so every reduce order gives the same pair."""
+    hi = jnp.sum(t >> 8, axis=axis)
+    lo = jnp.sum(t & 255, axis=axis)
+    return jnp.stack([hi + (lo >> 8), lo & 255], axis=-1)
+
+
+def from_fixed_sum(pair, frac_bits: int):
+    """float32 value of a (summed) :func:`fx_sum` pair in Q(frac_bits)."""
+    p = jnp.asarray(pair)
+    return ((p[..., 0].astype(jnp.float32) * np.float32(256)
+             + p[..., 1].astype(jnp.float32)) / np.float32(1 << frac_bits))
 
 
 def saturate(x, dtype):

@@ -28,8 +28,8 @@ from ..elastic.state import pack_rng, unpack_rng
 from ..kernels import dispatch
 from ..systems import (ChunkPipeline, ChunkTick, System, chunk_schedule,
                        run_steps)
-from .fixed_point import (_shift_round, fx_dot_hybrid, from_fixed,
-                          mul_round_f32, to_fixed)
+from .fixed_point import (_shift_round, from_fixed_sum, fx_dot_hybrid,
+                          fx_sum, mul_round_f32, to_fixed)
 
 VERSIONS = ("fp32", "int32", "hyb", "bui")
 
@@ -107,8 +107,8 @@ def make_local_grad_int32(frac_bits: int, backend=None):
                               backend=be) + bq          # Q(f)
         err = (dot - yq) * mask                         # Q(f)
         prod = err[:, None] * Xq.astype(jnp.int32)      # Q(2f)
-        gw = jnp.sum(_shift_round(prod, frac_bits), 0)  # Q(f)
-        return {"gw": gw, "gb": jnp.sum(err)}
+        gw = fx_sum(_shift_round(prod, frac_bits), 0)   # Q(f) pairs
+        return {"gw": gw, "gb": fx_sum(err)}
     return _local
 
 
@@ -118,8 +118,8 @@ def make_local_grad_hyb(x8_frac: int, w16_frac: int, out_frac: int):
         dot = fx_dot_hybrid(Xq8, wq16, x8_frac, w16_frac, out_frac) + bq
         err = (dot - yq) * mask                          # Q(out_frac) int32
         prod = err[:, None] * Xq8.astype(jnp.int32)      # Q(out+x8)
-        gw = jnp.sum(_shift_round(prod, x8_frac), 0)     # Q(out_frac)
-        return {"gw": gw, "gb": jnp.sum(err)}
+        gw = fx_sum(_shift_round(prod, x8_frac), 0)      # Q(out_frac)
+        return {"gw": gw, "gb": fx_sum(err)}
     return _local
 
 
@@ -185,10 +185,10 @@ def make_gd_step_fns(quant_cfg: GdConfig):
 
     def update(carry, reduced):
         w, b, s = carry
-        # host-strategy reduces arrive as promoted numpy int64;
-        # jnp.asarray demotes to int32 exactly as the old host path did
-        gw = from_fixed(jnp.asarray(reduced["gw"]), f)
-        gb = from_fixed(jnp.asarray(reduced["gb"]), f)
+        # integer gradients arrive as summed fx_sum pairs (host-strategy
+        # reduces as promoted numpy int64 — small enough for int32)
+        gw = from_fixed_sum(reduced["gw"], f)
+        gb = from_fixed_sum(reduced["gb"], f)
         return apply(w, b, s, gw, gb), None
     return prepare, update
 

@@ -27,7 +27,7 @@ import numpy as np
 from ..kernels import dispatch
 from ..systems import (ChunkPipeline, ChunkTick, System, chunk_schedule,
                        run_steps)
-from .fixed_point import _shift_round, fx_dot_hybrid
+from .fixed_point import _shift_round, fx_dot_hybrid, fx_sum
 from .linreg import GdConfig, GdResult, make_gd_step_fns
 from .lut import SigmoidLut, build_sigmoid_lut, taylor_sigmoid_fixed
 
@@ -109,8 +109,8 @@ def make_local_grad(cfg: LogRegConfig, lut: Optional[SigmoidLut],
             p = taylor_sigmoid_fixed(z, f, terms=terms)   # Q(f)
             err = (p - yq) * mask
             prod = err[:, None] * Xq.astype(jnp.int32)
-            gw = jnp.sum(_shift_round(prod, f), 0)
-            return {"gw": gw, "gb": jnp.sum(err)}
+            gw = fx_sum(_shift_round(prod, f), 0)
+            return {"gw": gw, "gb": fx_sum(err)}
         return _local_int32_taylor
 
     if cfg.version in ("int32_lut_mram", "int32_lut_wram"):
@@ -124,8 +124,8 @@ def make_local_grad(cfg: LogRegConfig, lut: Optional[SigmoidLut],
             p = _shift_round(p15, lut.value_frac - f)     # -> Q(f)
             err = (p - yq) * mask
             prod = err[:, None] * Xq.astype(jnp.int32)
-            gw = jnp.sum(_shift_round(prod, f), 0)
-            return {"gw": gw, "gb": jnp.sum(err)}
+            gw = fx_sum(_shift_round(prod, f), 0)
+            return {"gw": gw, "gb": fx_sum(err)}
         return _local_int32_lut
 
     # hyb_lut / bui_lut — identical numerics (paper §3.1/§3.2); the
@@ -140,8 +140,8 @@ def make_local_grad(cfg: LogRegConfig, lut: Optional[SigmoidLut],
         p = _shift_round(p15, lut.value_frac - f)
         err = (p - yq) * mask
         prod = err[:, None] * Xq8.astype(jnp.int32)
-        gw = jnp.sum(_shift_round(prod, x8), 0)
-        return {"gw": gw, "gb": jnp.sum(err)}
+        gw = fx_sum(_shift_round(prod, x8), 0)
+        return {"gw": gw, "gb": fx_sum(err)}
     return _local_hyb_lut
 
 

@@ -18,7 +18,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..compat import axis_size
 
 
 def hierarchical_psum(x: jnp.ndarray, *, intra_axis: str = "data",
@@ -29,7 +28,7 @@ def hierarchical_psum(x: jnp.ndarray, *, intra_axis: str = "data",
     leading dim of ``x`` to be divisible by the intra-axis size (pad at
     call site otherwise; the trainer's grad vectors satisfy this).
     """
-    n_intra = axis_size(intra_axis)
+    n_intra = jax.lax.axis_size(intra_axis)
     lead = x.shape[0]
     if lead % n_intra != 0:
         # fall back to the flat reduction for awkward shapes
@@ -45,7 +44,7 @@ def hierarchical_psum(x: jnp.ndarray, *, intra_axis: str = "data",
 
 def hierarchical_pmean(x: jnp.ndarray, *, intra_axis: str = "data",
                        inter_axis: str = "pod") -> jnp.ndarray:
-    total = axis_size(intra_axis) * axis_size(inter_axis)
+    total = jax.lax.axis_size(intra_axis) * jax.lax.axis_size(inter_axis)
     return hierarchical_psum(x, intra_axis=intra_axis,
                              inter_axis=inter_axis) / total
 

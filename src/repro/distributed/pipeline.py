@@ -29,7 +29,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from ..compat import pcast, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -58,7 +57,7 @@ def pipeline_apply(mesh: Mesh, stage_axis: str, block_fn: Callable,
     pspecs = jax.tree_util.tree_map(lambda _: P(stage_axis), staged_params)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(pspecs, P()), out_specs=P())
     def run(params_stage, xs):
         # local view: leading stage axis is length-1 on each shard
@@ -85,10 +84,11 @@ def pipeline_apply(mesh: Mesh, stage_axis: str, block_fn: Callable,
             return (buf, outs), None
 
         # mark the carries as varying over the stage axis (shard_map VMA
-        # typing: they become stage-dependent after the first ppermute;
-        # identity on pre-VMA jax via repro.compat)
-        buf0 = pcast(jnp.zeros_like(xs[0]), (stage_axis,), to="varying")
-        outs0 = pcast(jnp.zeros_like(xs), (stage_axis,), to="varying")
+        # typing: they become stage-dependent after the first ppermute)
+        buf0 = jax.lax.pcast(jnp.zeros_like(xs[0]), (stage_axis,),
+                             to="varying")
+        outs0 = jax.lax.pcast(jnp.zeros_like(xs), (stage_axis,),
+                              to="varying")
         (_, outs), _ = jax.lax.scan(tick, (buf0, outs0),
                                     jnp.arange(n_ticks))
         # replicate the last stage's outputs to every shard

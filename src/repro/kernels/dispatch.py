@@ -17,7 +17,8 @@ PIM-Opt and the DPU programmability study both call for):
     ``REPRO_KERNEL_BACKEND`` environment override;
   * :func:`launch` — the uniform entry: ``launch(op, *args,
     backend=..., **kw)`` routes to the family's kernel or ref
-    implementation and falls back to ref when Pallas is unavailable.
+    implementation.  A Pallas backend is always honoured: nothing
+    falls back to ref behind the caller's back.
 
 Every op family registers a (pallas, ref) implementation pair from its
 ``ops.py`` at import time; :func:`launch` lazily imports the families on
@@ -38,8 +39,6 @@ import os
 from typing import Callable, Dict, Optional, Union
 
 import jax
-
-from .pallas_compat import HAS_PALLAS, pallas_unavailable_reason
 
 
 class KernelBackend(enum.Enum):
@@ -64,13 +63,6 @@ BackendLike = Union[None, str, KernelBackend]
 BACKEND_ENV_VAR = "REPRO_KERNEL_BACKEND"
 
 
-def _platform() -> str:
-    try:
-        return jax.default_backend()
-    except Exception:  # pragma: no cover - no devices at all
-        return "cpu"
-
-
 def default_backend() -> KernelBackend:
     """Auto-select the backend for this process.
 
@@ -83,18 +75,13 @@ def default_backend() -> KernelBackend:
     env = os.environ.get(BACKEND_ENV_VAR)
     if env:
         return resolve_backend(env)
-    if HAS_PALLAS and _platform() == "tpu":
+    if jax.default_backend() == "tpu":
         return KernelBackend.PALLAS_TPU
     return KernelBackend.JNP_REF
 
 
 def resolve_backend(spec: BackendLike = None) -> KernelBackend:
-    """Coerce None/string/enum to a usable :class:`KernelBackend`.
-
-    A Pallas backend silently degrades to ``jnp_ref`` when this jax
-    build has no Pallas at all — the ref oracles are semantically
-    identical (asserted by the parity tests), so degrading is safe.
-    """
+    """Coerce None/string/enum to a :class:`KernelBackend`."""
     if spec is None:
         be = default_backend()
     elif isinstance(spec, KernelBackend):
@@ -109,8 +96,6 @@ def resolve_backend(spec: BackendLike = None) -> KernelBackend:
     else:
         raise TypeError(f"backend must be None, str or KernelBackend, "
                         f"got {type(spec).__name__}")
-    if be.is_pallas and not HAS_PALLAS:
-        return KernelBackend.JNP_REF
     return be
 
 
@@ -192,7 +177,7 @@ def available_ops() -> tuple:
 
 def launch(op: str, *args, backend: BackendLike = None, **kwargs):
     """Run kernel-family op ``op`` on ``backend`` (auto-selected when
-    None).  Jnp-ref fallback engages when Pallas is unavailable."""
+    None)."""
     entry = get_op(op)
     be = resolve_backend(backend)
     launch_counts[op] = launch_counts.get(op, 0) + 1
@@ -223,5 +208,5 @@ __all__ = [
     "KernelBackend", "BACKEND_ENV_VAR", "default_backend",
     "resolve_backend", "legacy_backend", "register_op", "get_op",
     "available_ops", "launch", "legacy_launch", "launch_counts",
-    "backend_tag", "HAS_PALLAS", "pallas_unavailable_reason",
+    "backend_tag",
 ]

@@ -3,16 +3,21 @@
 TPU adaptation of the paper's streaming layout: the DPU version reorders
 feature values so each leaf is contiguous and streams MRAM->WRAM.  On TPU
 the same property — "every byte fetched from HBM is used by exactly one
-streaming pass" — is achieved by tiling points into (block_n x F) VMEM
-blocks and turning both per-leaf threshold selection and per-(leaf,class)
-count scatter into **one-hot matmuls** (MXU work, no data-dependent
-scatter, which Mosaic does not support):
+streaming pass" — is achieved by tiling points into (F x block_n) VMEM
+blocks, one point per lane (lane-dense also under ``vmap``), and turning
+both per-leaf threshold selection and per-(leaf, class) count scatter
+into **one-hot matmuls** (MXU work, no data-dependent scatter, which
+Mosaic does not support):
 
-  t[i, f]      = onehot_leaf[i, :] @ thresholds[:, f]
-  counts[s, f] = onehot_seg[:, s].T @ below[:, f]        s = leaf*C + class
+  t[f, i]      = thresholds[:, f] . onehot_leaf[:, i]
+  counts[s, f] = onehot_seg[s, :] . below[f, :]          s = leaf*C + class
 
-Thresholds and the count accumulators stay pinned in VMEM across the grid;
-point blocks stream — the direct analogue of the DPU's DMA streaming.
+Both stay exact in bfloat16: the thresholds arrive as three bfloat16
+pieces whose float32 sum is the threshold itself (each one-hot column
+selects exactly one leaf), and the 0/1 counts of a block sum exactly in
+float32.  Thresholds and the count accumulators stay pinned in VMEM
+across the grid; point blocks stream — the direct analogue of the DPU's
+DMA streaming.
 """
 from __future__ import annotations
 
@@ -23,9 +28,34 @@ import jax.numpy as jnp
 
 from ..pallas_compat import pallas_call, pl
 
+_NN = (((1,), (0,)), ((), ()))
+_NT = (((1,), (1,)), ((), ()))
+# the one-hot dots are exact in one bfloat16 pass; pinned so a caller's
+# default_matmul_precision cannot ask Mosaic for more
+_ONE_PASS = jax.lax.Precision.DEFAULT
 
-def _gini_kernel(x_ref, seg_ref, leaf_ref, th_ref, counts_ref, totals_ref,
-                 *, n_slots: int):
+
+def _trunc_bf16(x: jnp.ndarray) -> jnp.ndarray:
+    """float32 -> bfloat16 rounding toward zero (never overflows)."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32) & jnp.uint32(
+        0xFFFF0000)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32).astype(
+        jnp.bfloat16)
+
+
+def split_bf16x3(x: jnp.ndarray) -> jnp.ndarray:
+    """float32 -> (3, ...) bfloat16 pieces holding the top, middle and
+    bottom 8 significand bits of ``x``: their float32 sum ``(hi + mid) +
+    lo`` is ``x`` exactly for every finite ``x`` with ``|x| >= 2^-103``
+    (below that the bottom piece is subnormal and may round)."""
+    hi = _trunc_bf16(x)
+    r1 = x - hi.astype(jnp.float32)
+    mid = _trunc_bf16(r1)
+    lo = (r1 - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return jnp.stack([hi, mid, lo])
+
+
+def _gini_kernel(xt_ref, seg_ref, leaf_ref, th_ref, counts_ref, totals_ref):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -33,24 +63,28 @@ def _gini_kernel(x_ref, seg_ref, leaf_ref, th_ref, counts_ref, totals_ref,
         counts_ref[...] = jnp.zeros_like(counts_ref)
         totals_ref[...] = jnp.zeros_like(totals_ref)
 
-    x = x_ref[...]                                   # (bn, F) f32
-    seg = seg_ref[...]                               # (bn,) int32 leaf*C+y
-    leaf = leaf_ref[...]                             # (bn,) int32
-    th = th_ref[...]                                 # (L, F) f32
+    xt = xt_ref[...]                                 # (F, bn) f32
+    seg = seg_ref[...]                               # (1, bn) leaf*C+y
+    leaf = leaf_ref[...]                             # (1, bn)
+    n_leaves = th_ref.shape[2]
+    n_slots = counts_ref.shape[0]
+    bn = xt.shape[1]
 
-    n_leaves = th.shape[0]
-    oh_leaf = (leaf[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (1, n_leaves), 1)).astype(jnp.float32)
-    t = jax.lax.dot_general(oh_leaf, th, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    below = (x <= t).astype(jnp.int32)               # (bn, F)
+    oh_leaf = (jax.lax.broadcasted_iota(jnp.int32, (n_leaves, bn), 0)
+               == leaf).astype(jnp.float32).astype(jnp.bfloat16)
+    t = [jax.lax.dot_general(th_ref[p], oh_leaf, _NN, precision=_ONE_PASS,
+                             preferred_element_type=jnp.float32)
+         for p in range(3)]                          # (F, bn) each
+    below = (xt <= (t[0] + t[1]) + t[2]).astype(jnp.float32)
 
-    oh_seg = (seg[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (1, n_slots), 1)).astype(jnp.int32)
+    oh_seg = (jax.lax.broadcasted_iota(jnp.int32, (n_slots, bn), 0)
+              == seg).astype(jnp.float32)            # (n_slots, bn)
     counts_ref[...] += jax.lax.dot_general(
-        oh_seg, below, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)            # (n_slots, F)
-    totals_ref[...] += jnp.sum(oh_seg, axis=0)
+        oh_seg.astype(jnp.bfloat16), below.astype(jnp.bfloat16), _NT,
+        precision=_ONE_PASS,
+        preferred_element_type=jnp.float32).astype(jnp.int32)
+    totals_ref[...] += jnp.sum(oh_seg, axis=1,
+                               keepdims=True).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("n_classes", "block_n",
@@ -66,26 +100,26 @@ def gini_counts(x: jnp.ndarray, y: jnp.ndarray, leaf: jnp.ndarray,
     n_slots = n_leaves * n_classes
     bn = min(block_n, n)
     assert n % bn == 0, (n, bn)
-    seg = leaf * n_classes + y
+    seg = (leaf * n_classes + y).reshape(1, n)
     counts, totals = pallas_call(
-        functools.partial(_gini_kernel, n_slots=n_slots),
+        _gini_kernel,
         grid=(n // bn,),
         in_specs=[
-            pl.BlockSpec((bn, f), lambda i: (i, 0)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((n_leaves, f), lambda i: (0, 0)),  # pinned
+            pl.BlockSpec((f, bn), lambda i: (0, i)),
+            pl.BlockSpec((1, bn), lambda i: (0, i)),
+            pl.BlockSpec((1, bn), lambda i: (0, i)),
+            pl.BlockSpec((3, f, n_leaves), lambda i: (0, 0, 0)),  # pinned
         ],
         out_specs=[
             pl.BlockSpec((n_slots, f), lambda i: (0, 0)),   # accumulated
-            pl.BlockSpec((n_slots,), lambda i: (0,)),
+            pl.BlockSpec((n_slots, 1), lambda i: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_slots, f), jnp.int32),
-            jax.ShapeDtypeStruct((n_slots,), jnp.int32),
+            jax.ShapeDtypeStruct((n_slots, 1), jnp.int32),
         ],
         dimension_semantics=("arbitrary",),
         interpret=interpret,
-    )(x, seg, leaf, thresholds)
+    )(x.T, seg, leaf.reshape(1, n), split_bf16x3(thresholds.T))
     return (counts.reshape(n_leaves, n_classes, f),
             totals.reshape(n_leaves, n_classes))

@@ -1,15 +1,23 @@
 """Pallas TPU kernel: K-Means assignment + accumulation (paper §3.4).
 
-TPU adaptation: the DPU loops over points computing 16-bit multiplies; the
-MXU-native formulation is  argmin_k(||c_k||^2 - 2 x.c_k)  — an int16 x int16
--> int32 matmul per (points-block x centroids) tile, followed by a one-hot
-matmul that accumulates per-cluster coordinate sums on-chip.  Centroids
-(K x F) stay pinned in VMEM across the whole grid; point blocks stream
-HBM->VMEM, which is the same streaming-bank access pattern the paper
-engineers for the DPU (Recommendation #6).
+TPU adaptation: the DPU loops over points computing 16-bit multiplies.
+Here the points arrive transposed, one point per lane, so every block
+and the label output are lane-dense (also under ``vmap``, which adds a
+squeezed cores axis in front):
 
-Outputs ``sums``/``counts`` map every grid step to block (0, 0) and are
-accumulated in place across the sequential grid (revisiting semantics).
+  * distances ``||c_k||^2 - 2 x.c_k`` (||x||^2 omitted, argmin-invariant)
+    are exact int32 VPU multiply-adds over the F features — the
+    contraction is tiny, and the MXU takes no int32 operands;
+  * the per-cluster coordinate sums are one-hot matmuls on the MXU.  A
+    coordinate is split into its high byte and its low byte, each an
+    integer in [-128, 255] that bfloat16 holds exactly; a block's sums
+    stay below 2^24, so float32 accumulation is exact too, and the two
+    halves recombine in int32.
+
+Centroids (K x F) stay pinned in VMEM across the whole grid; point blocks
+stream HBM->VMEM, the streaming-bank access pattern the paper engineers
+for the DPU (Recommendation #6).  ``sums``/``counts`` map every grid step
+to block (0, 0) and accumulate in place across the sequential grid.
 """
 from __future__ import annotations
 
@@ -20,8 +28,14 @@ import jax.numpy as jnp
 
 from ..pallas_compat import pallas_call, pl
 
+# contract the lane (point) axis of both operands: (K, bn) x (F, bn)
+_NT = (((1,), (1,)), ((), ()))
+# the byte dots are exact in one bfloat16 pass; pinned so a caller's
+# default_matmul_precision cannot ask Mosaic for more
+_ONE_PASS = jax.lax.Precision.DEFAULT
 
-def _kmeans_kernel(x_ref, c_ref, labels_ref, sums_ref, counts_ref):
+
+def _kmeans_kernel(xt_ref, c_ref, labels_ref, sums_ref, counts_ref):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -29,52 +43,67 @@ def _kmeans_kernel(x_ref, c_ref, labels_ref, sums_ref, counts_ref):
         sums_ref[...] = jnp.zeros_like(sums_ref)
         counts_ref[...] = jnp.zeros_like(counts_ref)
 
-    x = x_ref[...].astype(jnp.int32)            # (bn, F)
+    xt = xt_ref[...].astype(jnp.int32)          # (F, bn)
     c = c_ref[...].astype(jnp.int32)            # (K, F)
-    cross = jax.lax.dot_general(x, c, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.int32)
-    cnorm = jnp.sum(c * c, axis=1)
-    dist = cnorm[None, :] - 2 * cross           # (bn, K)
-    labels = jnp.argmin(dist, axis=1).astype(jnp.int32)
+    k, f = c.shape
+    cross = jnp.zeros((k, xt.shape[1]), jnp.int32)
+    for j in range(f):                          # (K, 1) * (1, bn)
+        cross = cross + c[:, j:j + 1] * xt[j:j + 1, :]
+    cnorm = jnp.sum(c * c, axis=1, keepdims=True)            # (K, 1)
+    dist = cnorm - 2 * cross                                 # (K, bn)
+    kid = jax.lax.broadcasted_iota(jnp.int32, dist.shape, 0)
+    best = jnp.min(dist, axis=0, keepdims=True)
+    # first minimum, as jnp.argmin breaks ties
+    labels = jnp.min(jnp.where(dist == best, kid, k), axis=0,
+                     keepdims=True)                          # (1, bn)
     labels_ref[...] = labels
 
-    k = c.shape[0]
-    onehot = (labels[:, None] ==
-              jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)).astype(jnp.int32)
-    sums_ref[...] += jax.lax.dot_general(
-        onehot, x, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)       # (K, F)
-    counts_ref[...] += jnp.sum(onehot, axis=0)
+    onehot = kid == labels                                   # (K, bn)
+    oh = onehot.astype(jnp.float32).astype(jnp.bfloat16)
+    hi = (xt >> 8).astype(jnp.float32).astype(jnp.bfloat16)
+    lo = (xt & 255).astype(jnp.float32).astype(jnp.bfloat16)
+    s_hi = jax.lax.dot_general(oh, hi, _NT, precision=_ONE_PASS,
+                               preferred_element_type=jnp.float32)
+    s_lo = jax.lax.dot_general(oh, lo, _NT, precision=_ONE_PASS,
+                               preferred_element_type=jnp.float32)
+    sums_ref[...] += (s_hi.astype(jnp.int32) * 256
+                      + s_lo.astype(jnp.int32))              # (K, F)
+    counts_ref[...] += jnp.sum(onehot.astype(jnp.int32), axis=1,
+                               keepdims=True)                # (K, 1)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def kmeans_assign(x_q: jnp.ndarray, c_q: jnp.ndarray, *,
                   block_n: int = 1024, interpret: bool = False):
     """x_q int16 [N, F]; c_q int16 [K, F] ->
-    (labels int32 [N], sums int32 [K, F], counts int32 [K])."""
+    (labels int32 [N], sums int32 [K, F], counts int32 [K]).
+
+    Exact for points in the int16 range as long as a block of
+    ``block_n`` rows keeps every per-cluster coordinate sum of either
+    byte below 2^24 (``block_n <= 65,793``)."""
     n, f = x_q.shape
     k, f2 = c_q.shape
     assert f == f2
     bn = min(block_n, n)
     assert n % bn == 0, (n, bn)
-    grid = (n // bn,)
-    return pallas_call(
+    labels, sums, counts = pallas_call(
         _kmeans_kernel,
-        grid=grid,
+        grid=(n // bn,),
         in_specs=[
-            pl.BlockSpec((bn, f), lambda i: (i, 0)),
+            pl.BlockSpec((f, bn), lambda i: (0, i)),
             pl.BlockSpec((k, f), lambda i: (0, 0)),   # centroids pinned
         ],
         out_specs=[
-            pl.BlockSpec((bn,), lambda i: (i,)),
+            pl.BlockSpec((1, bn), lambda i: (0, i)),
             pl.BlockSpec((k, f), lambda i: (0, 0)),   # accumulated in place
-            pl.BlockSpec((k,), lambda i: (0,)),
+            pl.BlockSpec((k, 1), lambda i: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.int32),
+            jax.ShapeDtypeStruct((1, n), jnp.int32),
             jax.ShapeDtypeStruct((k, f), jnp.int32),
-            jax.ShapeDtypeStruct((k,), jnp.int32),
+            jax.ShapeDtypeStruct((k, 1), jnp.int32),
         ],
         dimension_semantics=("arbitrary",),
         interpret=interpret,
-    )(x_q, c_q)
+    )(x_q.T, c_q)
+    return labels[0], sums, counts[:, 0]

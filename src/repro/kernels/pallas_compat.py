@@ -1,96 +1,33 @@
-"""Single-point resolution of drifted Pallas TPU APIs (DESIGN.md §6.1).
+"""Where every kernel gets its Pallas TPU pieces from (DESIGN.md §6.1).
 
-The Pallas TPU surface has moved across jax releases:
-
-  * ``pltpu.TPUCompilerParams`` was renamed ``pltpu.CompilerParams``
-    (and on very old releases compiler params were a ``mosaic_params``
-    dict) — the source of the ``AttributeError: CompilerParams`` drift
-    that killed every kernel in this repo at once;
-  * some builds ship without Pallas at all (no Mosaic backend compiled
-    in), in which case the kernels must be skippable rather than fatal.
-
-Every ``kernels/*/kernel.py`` imports **this module only** for the
-drift-prone pieces; none of them touch ``pltpu`` attributes directly.
-When the next rename lands, it gets fixed here, once.
+Every ``kernels/*/kernel.py`` builds its ``pallas_call`` through
+:func:`pallas_call` below, which turns a plain tuple of dimension
+semantics into ``pltpu.CompilerParams``.  Pallas is a hard dependency:
+a jax build without it fails at import, never by quietly routing
+kernels to another backend.
 
 Nothing here imports the rest of ``repro`` — this is the bottom of the
 kernel-layer dependency graph (dispatch.py sits on top).
 """
 from __future__ import annotations
 
-from typing import Any, Optional
-
-HAS_PALLAS = True
-_IMPORT_ERROR: Optional[Exception] = None
-
-try:  # pragma: no cover - exercised implicitly by every kernel import
-    from jax.experimental import pallas as pl  # noqa: F401
-except Exception as e:  # pallas not in this jax build
-    pl = None  # type: ignore[assignment]
-    HAS_PALLAS = False
-    _IMPORT_ERROR = e
-
-try:  # the TPU sub-package can be missing even when pallas core exists
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-except Exception as e:  # pragma: no cover
-    pltpu = None  # type: ignore[assignment]
-    HAS_PALLAS = False
-    if _IMPORT_ERROR is None:
-        _IMPORT_ERROR = e
-
-#: the compiler-params class under whichever name this jax spells it
-CompilerParams: Optional[type] = None
-if pltpu is not None:
-    CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams", None)
-
-
-def pallas_unavailable_reason() -> Optional[str]:
-    """Human-readable reason Pallas cannot be used, or None if it can."""
-    if HAS_PALLAS:
-        return None
-    return f"pallas unavailable in this jax build: {_IMPORT_ERROR!r}"
-
-
-def compiler_params(dimension_semantics=None, **kwargs) -> Optional[Any]:
-    """Build a compiler-params object if this jax supports one.
-
-    Returns None when Pallas has no compiler-params class (or when no
-    fields were requested); callers pass the result straight to
-    ``pallas_call(compiler_params=...)``, where None means "defaults".
-    """
-    if CompilerParams is None:
-        return None
-    if dimension_semantics is not None:
-        kwargs["dimension_semantics"] = tuple(dimension_semantics)
-    if not kwargs:
-        return None
-    try:
-        return CompilerParams(**kwargs)
-    except TypeError:
-        # field-name drift inside the params class itself: degrade to
-        # compiler defaults rather than failing the kernel outright
-        return None
+from jax.experimental import pallas as pl  # noqa: F401  (re-exported)
+from jax.experimental.pallas import tpu as pltpu
 
 
 def vmem_scratch(shape, dtype):
-    """``pltpu.VMEM`` scratch allocation (drift-safe accessor)."""
-    if pltpu is None:
-        raise RuntimeError(pallas_unavailable_reason())
+    """``pltpu.VMEM`` scratch allocation."""
     return pltpu.VMEM(tuple(shape), dtype)
 
 
 def pallas_call(kernel_fn, *, grid=None, in_specs=None, out_specs=None,
                 out_shape=None, scratch_shapes=None,
                 dimension_semantics=None, interpret: bool = False):
-    """Drift-resolved ``pl.pallas_call`` wrapper used by every kernel.
+    """``pl.pallas_call`` with the compiler parameters every kernel sets.
 
-    ``dimension_semantics`` is taken as a plain tuple of strings and
-    converted into whatever compiler-params object this jax wants; all
-    other arguments pass through unchanged.
+    ``dimension_semantics`` is a plain tuple of strings that becomes a
+    ``pltpu.CompilerParams``; all other arguments pass through.
     """
-    if pl is None:
-        raise RuntimeError(pallas_unavailable_reason())
     kwargs: dict = {"out_shape": out_shape, "interpret": interpret}
     if grid is not None:
         kwargs["grid"] = grid
@@ -100,7 +37,7 @@ def pallas_call(kernel_fn, *, grid=None, in_specs=None, out_specs=None,
         kwargs["out_specs"] = out_specs
     if scratch_shapes is not None:
         kwargs["scratch_shapes"] = scratch_shapes
-    params = compiler_params(dimension_semantics=dimension_semantics)
-    if params is not None:
-        kwargs["compiler_params"] = params
+    if dimension_semantics is not None:
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=tuple(dimension_semantics))
     return pl.pallas_call(kernel_fn, **kwargs)

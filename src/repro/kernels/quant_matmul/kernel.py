@@ -28,10 +28,11 @@ def _quant_matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = a_ref[...].astype(jnp.int32)
-    b = b_ref[...].astype(jnp.int32)
+    # int8 operands straight into the MXU; only the accumulator is int32
     acc_ref[...] += jax.lax.dot_general(
-        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+        a_ref[...], b_ref[...], (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.int32)
 
     @pl.when(k == n_k - 1)
     def _store():
@@ -68,13 +69,13 @@ def int_matmul(a_q: jnp.ndarray, b_q: jnp.ndarray, *, bm: int = 128,
     )(a_q, b_q)
 
 
-def _fx_matvec_kernel(x_ref, w_ref, o_ref, *, frac_bits: int):
-    x = x_ref[...].astype(jnp.int32)                 # (bn, F)
-    w = w_ref[...].astype(jnp.int32)                 # (1, F)
+def _fx_matvec_kernel(xt_ref, w_ref, o_ref, *, frac_bits: int):
+    x = xt_ref[...].astype(jnp.int32)                # (F, bn) rows on lanes
+    w = w_ref[...].astype(jnp.int32)                 # (F, 1)
     prod = x * w                                     # Q(2f)
     if frac_bits:
         prod = (prod + (1 << (frac_bits - 1))) >> frac_bits
-    o_ref[...] = jnp.sum(prod, axis=1)               # (bn,) Q(f)
+    o_ref[...] = jnp.sum(prod, axis=0, keepdims=True)  # (1, bn) Q(f)
 
 
 @functools.partial(jax.jit, static_argnames=("frac_bits", "block_n",
@@ -84,22 +85,25 @@ def fx_matvec(x_q: jnp.ndarray, w_q: jnp.ndarray, *, frac_bits: int,
     """Q-format row-dot: int32[N, F] x int32[F] -> int32[N], each product
     shifted back to Q(frac_bits) with round-to-nearest BEFORE accumulation
     (the paper's 32-bit DPU dot-product ordering; bit-identical to
-    ``fixed_point.fx_dot``).  VPU work: rows stream through the grid, the
-    weight vector stays pinned — the kernel-tier path of the LIN/LOG
-    INT32 versions' matmul."""
+    ``fixed_point.fx_dot``).  VPU work: the rows are transposed onto the
+    lane axis so every block and the output are lane-dense (also under
+    ``vmap``, which adds a squeezed cores axis in front), the weight
+    vector stays pinned — the kernel-tier path of the LIN/LOG INT32
+    versions' matmul."""
     n, f = x_q.shape
     assert w_q.shape == (f,), (x_q.shape, w_q.shape)
     bn = min(block_n, n)
     assert n % bn == 0, (n, bn)
-    return pallas_call(
+    out = pallas_call(
         functools.partial(_fx_matvec_kernel, frac_bits=frac_bits),
         grid=(n // bn,),
         in_specs=[
-            pl.BlockSpec((bn, f), lambda i: (i, 0)),
-            pl.BlockSpec((1, f), lambda i: (0, 0)),  # weights pinned
+            pl.BlockSpec((f, bn), lambda i: (0, i)),
+            pl.BlockSpec((f, 1), lambda i: (0, 0)),  # weights pinned
         ],
-        out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
-        dimension_semantics=("arbitrary",),
+        out_specs=pl.BlockSpec((1, bn), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, n), jnp.int32),
+        dimension_semantics=("parallel",),
         interpret=interpret,
-    )(x_q, w_q.reshape(1, f))
+    )(x_q.T, w_q.reshape(f, 1))
+    return out[0]

@@ -9,9 +9,10 @@ Ops (registered with :mod:`repro.kernels.dispatch`):
                       sum) — the eager apply and the deferred flush both
                       route through this single op.
 
-The pallas wrappers pad ragged axes (batch for gather, rows for
-scatter) with the sentinel ids from :mod:`.ref`, which can never match
-a real lookup — padded work contributes exact zeros and is sliced off.
+The pallas wrappers pad ragged axes (batch and rows for gather, rows
+for scatter) with the sentinel ids from :mod:`.ref`, which can never
+match a real lookup — padded work contributes exact zeros and is sliced
+off.
 """
 from __future__ import annotations
 
@@ -30,30 +31,33 @@ def _pad_to(x, n, fill):
     return jnp.concatenate([x, pad], axis=0)
 
 
-def _emb_gather_ref(table, ids, idx, *, block_b: int = 256):
+def _emb_gather_ref(table, ids, idx, *, block_b: int = 512):
     del block_b  # jnp oracle needs no tiling
     return emb_gather_ref(table, ids, idx)
 
 
 def _emb_gather_pallas(table, ids, idx, *, interpret: bool = True,
-                       block_b: int = 256):
-    b = idx.shape[0]
+                       block_b: int = 512, block_r: int = 1024):
+    b, r = idx.shape[0], table.shape[0]
     if b == 0:  # empty batch: nothing to look up
         return jnp.zeros((0, table.shape[1]), table.dtype)
-    bb = min(block_b, b)
+    bb, br = min(block_b, b), min(block_r, r)
     b_pad = -(-b // bb) * bb
-    out = _gather_kernel(table, ids, _pad_to(idx, b_pad, IDX_PAD),
-                         block_b=bb, interpret=interpret)
+    r_pad = -(-r // br) * br
+    out = _gather_kernel(_pad_to(table, r_pad, 0),
+                         _pad_to(ids, r_pad, ROW_PAD_ID),
+                         _pad_to(idx, b_pad, IDX_PAD),
+                         block_b=bb, block_r=br, interpret=interpret)
     return out[:b]
 
 
-def _emb_scatter_add_ref(table, ids, idx, upd, *, block_r: int = 256):
+def _emb_scatter_add_ref(table, ids, idx, upd, *, block_r: int = 1024):
     del block_r
     return emb_scatter_add_ref(table, ids, idx, upd)
 
 
 def _emb_scatter_add_pallas(table, ids, idx, upd, *,
-                            interpret: bool = True, block_r: int = 256):
+                            interpret: bool = True, block_r: int = 1024):
     if idx.shape[0] == 0:  # empty batch: table unchanged (ref adds 0)
         return table + jnp.zeros_like(table)
     r = table.shape[0]
@@ -65,7 +69,7 @@ def _emb_scatter_add_pallas(table, ids, idx, upd, *,
     return out[:r]
 
 
-def emb_gather(table, ids, idx, *, backend=None, block_b: int = 256):
+def emb_gather(table, ids, idx, *, backend=None, block_b: int = 512):
     """Shard-local lookup: [R, D] x [B] global ids -> [B, D] partials."""
     from ..dispatch import launch
     return launch("emb_gather", table, ids, idx, backend=backend,
@@ -73,7 +77,7 @@ def emb_gather(table, ids, idx, *, backend=None, block_b: int = 256):
 
 
 def emb_scatter_add(table, ids, idx, upd, *, backend=None,
-                    block_r: int = 256):
+                    block_r: int = 1024):
     """Duplicate-safe batched row update: segment-sum [B, D] into [R, D]."""
     from ..dispatch import launch
     return launch("emb_scatter_add", table, ids, idx, upd,

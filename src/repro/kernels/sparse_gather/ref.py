@@ -11,11 +11,13 @@ The one-hot matmul formulation is the load-bearing choice:
 
 * ``gather``: each one-hot row has at most one 1 (ids are unique within
   a shard), so the "sum" is a pure selection — exact in every dtype.
-* ``scatter_add``: duplicate batch indices land in the SAME one-hot row
-  and are summed by a single ``dot_general`` over the whole batch axis,
+* ``scatter_add``: duplicate batch indices hit the SAME table row's
+  one-hot column and are summed by a single ``dot_general`` over the
+  whole batch axis,
   i.e. a segment-sum — duplicate-safe with one fixed reduction order
   shared by the Pallas kernel, so ref and kernel stay bit-exact.
 
+Matmuls run at float32 precision (``Precision.HIGHEST``) and
 ``preferred_element_type`` pins the accumulator to the table dtype:
 int32 tables accumulate exactly in int32 (the Q-format fixed-point
 path); float tables accumulate in float32.
@@ -33,9 +35,9 @@ ROW_PAD_ID = -1
 IDX_PAD = -2
 
 
-def _onehot_dot(onehot, rows):
+def _onehot_dot(onehot, rows, dims=(((1,), (0,)), ((), ()))):
     return jax.lax.dot_general(
-        onehot, rows, (((1,), (0,)), ((), ())),
+        onehot, rows, dims, precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=rows.dtype)
 
 
@@ -55,5 +57,8 @@ def emb_scatter_add_ref(table: jnp.ndarray, ids: jnp.ndarray,
     """table: [R, D]; ids: int32 [R]; idx: int32 [B]; upd: [B, D]
     -> [R, D] with ``out[r] = table[r] + sum_b [ids[r]==idx[b]] upd[b]``
     (duplicate indices sum — segment-sum semantics)."""
-    onehot = (ids[:, None] == idx[None, :]).astype(table.dtype)  # (R, B)
-    return table + _onehot_dot(onehot, upd.astype(table.dtype))
+    # (B, R) one-hot contracted over the batch axis: the Pallas kernel's
+    # orientation, so both reduce each row's duplicates in one order
+    onehot = (idx[:, None] == ids[None, :]).astype(table.dtype)  # (B, R)
+    return table + _onehot_dot(onehot, upd.astype(table.dtype),
+                               (((0,), (0,)), ((), ())))

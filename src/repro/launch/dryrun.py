@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run (deliverable (e)).
 
 Lowers + compiles every (architecture x input-shape) cell on the
@@ -16,6 +13,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import re
 import time
 import traceback
@@ -280,4 +278,10 @@ def main():
 
 
 if __name__ == "__main__":
+    # a CPU-only dry run over 512 host devices; set here, not at
+    # import, so importing this module never touches XLA_FLAGS
+    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count"
+                               "=512")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -40,7 +40,7 @@ import numpy as np
 
 from ..api.registry import FitResult, TrainerSpec, Workload
 from ..core import linreg, logreg
-from ..core.fixed_point import from_fixed, mul_round_f32
+from ..core.fixed_point import from_fixed_sum, mul_round_f32
 from ..core.linreg import GdResult, _quantize_weights
 from ..core.logreg import _gd_version_of
 
@@ -102,7 +102,8 @@ class FusedGdSweep:
     Weights live host-side per lane, exactly as in the serial loop; per
     step the lanes' quantized weights are stacked to ``(K, F)``,
     broadcast once, and the vmapped per-core kernel produces per-lane
-    gradients ``{"gw": (K, F), "gb": (K,)}`` in a single ``map_reduce``.
+    gradients ``{"gw": (K, F), "gb": (K,)}`` in a single ``map_reduce``
+    (integer versions: ``fixed_point.fx_sum`` pairs, a trailing axis of 2).
     """
 
     def __init__(self, workload: Workload, specs: Sequence[TrainerSpec],
@@ -187,10 +188,8 @@ class FusedGdSweep:
         if cfg.version == "fp32":
             return (np.asarray(partial["gw"], np.float32),
                     np.asarray(partial["gb"], np.float32))
-        return (np.asarray(from_fixed(jnp.asarray(partial["gw"]),
-                                      cfg.frac_bits)),
-                np.asarray(from_fixed(jnp.asarray(partial["gb"]),
-                                      cfg.frac_bits)))
+        return (np.asarray(from_fixed_sum(partial["gw"], cfg.frac_bits)),
+                np.asarray(from_fixed_sum(partial["gb"], cfg.frac_bits)))
 
     def _make_lane_step_fns(self):
         """Lane-batched (prepare, update) for the StepProgram scan —
@@ -212,8 +211,8 @@ class FusedGdSweep:
                 GW = jnp.asarray(reduced["gw"], jnp.float32)
                 GB = jnp.asarray(reduced["gb"], jnp.float32)
             else:
-                GW = from_fixed(jnp.asarray(reduced["gw"]), f)
-                GB = from_fixed(jnp.asarray(reduced["gb"]), f)
+                GW = from_fixed_sum(reduced["gw"], f)
+                GB = from_fixed_sum(reduced["gb"], f)
             # two-rounding update pinned against FMA contraction, per
             # lane exactly as the serial trainers round (fixed_point.
             # mul_round_f32)

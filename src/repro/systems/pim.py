@@ -52,7 +52,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
 from ..core.quantization import storage_bytes
 from ..obs.trace import TRACER
 from .base import ReduceVia, System
@@ -173,10 +172,13 @@ class PimSystem(System):
             return jax.vmap(lambda *s: local_fn(*s, *replicated))(*sharded)
         mesh = self._mesh
 
+        # check_vma=False: the per-core kernels may be Pallas calls, whose
+        # out_shape carries no varying-axes annotation; every output is
+        # per-core (out_specs P("cores")) by construction
         @functools.partial(
-            shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(tuple(P("cores") for _ in sharded), P()),
-            out_specs=P("cores"))
+            out_specs=P("cores"), check_vma=False)
         def _shmap(shard_args, rep):
             local = [jnp.squeeze(a, 0) for a in shard_args]
             out = local_fn(*local, *rep)

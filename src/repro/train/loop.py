@@ -10,7 +10,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..compat import shard_map
 
 from repro.optim.adam import AdamW
 from repro.optim.grad_compression import ef_compress_psum
@@ -109,7 +108,7 @@ def _dp_call(mesh, axis, model, params, err, batch, compress, world):
     err_specs = jax.tree_util.tree_map(lambda _: P(), err)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(rep, err_specs, batch_specs),
         out_specs=((P(), rep), err_specs), check_vma=False)
     def run(params_, err_, batch_):

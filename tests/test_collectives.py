@@ -21,20 +21,18 @@ import sys; sys.path.insert(0, "src")
 import functools
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.distributed.collectives import hierarchical_psum
 
 mesh = jax.make_mesh((2, 4), ("pod", "data"))
 
-@functools.partial(shard_map, mesh=mesh,
+@functools.partial(jax.shard_map, mesh=mesh,
                    in_specs=P(("pod", "data")), out_specs=P())
 def flat(x):
     return jax.lax.psum(x, ("pod", "data"))
 
 # check_vma=False: the RS -> inter-AR -> AG composition is replicated in
-# value, but shard_map's varying-axes type system cannot infer that
-# (repro.compat translates the kwarg for older jax).
-@functools.partial(shard_map, mesh=mesh,
+# value, but shard_map's varying-axes type system cannot infer that.
+@functools.partial(jax.shard_map, mesh=mesh,
                    in_specs=P(("pod", "data")), out_specs=P(),
                    check_vma=False)
 def hier(x):

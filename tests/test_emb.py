@@ -21,7 +21,6 @@ from repro.api import make_estimator
 from repro.api.table import ShardedTable
 from repro.data.synthetic import make_recsys
 from repro.emb import EmbConfig, fit, fit_steps
-from repro.kernels.pallas_compat import HAS_PALLAS
 from repro.kernels.sparse_gather import (IDX_PAD, ROW_PAD_ID, emb_gather,
                                          emb_scatter_add)
 from repro.kernels.sparse_gather.ref import (emb_gather_ref,
@@ -111,9 +110,6 @@ class TestSparseGatherSemantics:
 # Pallas parity: interpret-mode kernels vs the jnp_ref oracle, bit-exact.
 # ---------------------------------------------------------------------------
 
-@pytest.mark.skipif(not HAS_PALLAS,
-                    reason="no Pallas in this jax build "
-                           "(dispatch degrades to jnp_ref)")
 class TestSparseGatherParity:
     @pytest.mark.parametrize("dtype", [np.int32, np.float32])
     @pytest.mark.parametrize("b", [1, 8, 20])   # 20 forces a ragged tail

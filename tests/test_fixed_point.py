@@ -2,9 +2,9 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro.core.fixed_point import (_shift_round, from_fixed, fx_dot,
-                                    fx_dot_hybrid, fx_mul, fx_recip,
-                                    to_fixed)
+from repro.core.fixed_point import (_shift_round, from_fixed,
+                                    from_fixed_sum, fx_dot, fx_dot_hybrid,
+                                    fx_mul, fx_recip, fx_sum, to_fixed)
 
 
 def test_to_from_fixed_roundtrip():
@@ -63,3 +63,21 @@ def test_fx_recip():
     d = rng.uniform(0.5, 8.0, 64).astype(np.float32)
     r = from_fixed(fx_recip(to_fixed(d, 10), 10), 10)
     assert np.abs(np.asarray(r) - 1.0 / d).max() < 0.01
+
+
+@pytest.mark.parametrize("term,n,shards", [
+    (-1024, 2_500_000, 64),   # SUSY's first LIN bias gradient: -2.56e9
+    (1023, 3_000_000, 1),     # one shard past int32
+    (-7, 1000, 8),            # small sums stay exact as well
+])
+def test_fx_sum_pairs_exact_past_int32(term, n, shards):
+    """Gradient sums outgrow int32 at the paper's dataset sizes; the
+    (hi, lo) pairs of shard partials add to the exact total."""
+    t = jnp.full((shards, n // shards), term, jnp.int32)
+    pairs = fx_sum(t, axis=1)                        # one pair per shard
+    assert pairs.shape == (shards, 2) and pairs.dtype == jnp.int32
+    total = term * (n // shards) * shards
+    got = float(from_fixed_sum(jnp.sum(pairs, axis=0), 0))
+    assert got == float(np.float32(total))
+    assert float(from_fixed_sum(jnp.sum(pairs, axis=0), 10)) \
+        == float(np.float32(total) / np.float32(1024))

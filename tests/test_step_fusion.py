@@ -192,9 +192,11 @@ def test_chunk_accounting_not_cached_across_widths(lin_data):
         snap = pim.stats.snapshot()
         linreg.fit(ds, cfg)
         d = pim.stats.delta(snap)
-        # fabric reduce legs: k x (gw:(F,), gb:()) int32 x n_cores,
-        # plus the chunk-boundary sync of the (w, b, s) carry
-        assert d.pim_to_cpu == k * (feat + 1) * 4 * CORES + (feat + 2) * 4
+        # fabric reduce legs: k x (gw:(F, 2), gb:(2,)) int32 fx_sum
+        # pairs x n_cores, plus the chunk-boundary sync of the (w, b, s)
+        # carry
+        assert d.pim_to_cpu == (k * (feat + 1) * 2 * 4 * CORES
+                                + (feat + 2) * 4)
 
 
 def test_hierarchical_chunk_accounting(lin_data):
@@ -212,8 +214,8 @@ def test_hierarchical_chunk_accounting(lin_data):
     d = pim.stats.delta(snap)
     assert d.kernel_launches == 1
     # HierarchicalReduce(8) on 8 cores -> 1 group; per-step rank
-    # partials: (1, F) int32 gw + (1,) int32 gb
-    per_step = (F + 1) * 4
+    # partials: (1, F, 2) int32 gw + (1, 2) int32 gb fx_sum pairs
+    per_step = (F + 1) * 2 * 4
     assert d.inter_core_via_host == k * per_step
     # matches the unfused hierarchical trajectory bit for bit
     pim2 = PimSystem(PimConfig(n_cores=CORES,

@@ -209,9 +209,9 @@ def test_pim_stats_unchanged_by_refactor(lin_data):
     linreg.fit(ds, cfg)
     d = pim.stats.delta(snap)
     assert d.dram_bytes == 0
-    # per step: fabric reduce ships (gw:(F,), gb:()) int32 per core;
-    # broadcast ships (w:(F,), b:()) int32 per core
-    assert d.pim_to_cpu == 5 * (F + 1) * 4 * CORES
+    # per step: fabric reduce ships (gw:(F, 2), gb:(2,)) int32 fx_sum
+    # pairs per core; broadcast ships (w:(F,), b:()) int32 per core
+    assert d.pim_to_cpu == 5 * (F + 1) * 2 * 4 * CORES
     assert d.cpu_to_pim == 5 * (F + 1) * 4 * CORES
 
 

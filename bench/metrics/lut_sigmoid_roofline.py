@@ -1,0 +1,6 @@
+"""Pallas kernel ``lut_sigmoid_vmem``: its share of its roofline, from the
+device time of its events in the trace (moves ``fit_s``; LOG cells)."""
+
+
+def read(run):
+    return run.kernel_roofline("lut_sigmoid")

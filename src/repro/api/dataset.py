@@ -77,11 +77,8 @@ class PimDataset:
         view = self._views.get(key)
         if view is None:
             from ..obs.trace import TRACER   # local: api -> obs, no cycle
-            if TRACER.enabled:
-                track = getattr(self.system, "_trace_track", "system:?")
-                with TRACER.span(f"shard:{key[0]}", track, "transfer"):
-                    view = builder()
-            else:
+            track = getattr(self.system, "_trace_track", "system:?")
+            with TRACER.span("repro.view", track, "transfer", view=key[0]):
                 view = builder()
             self._views[key] = view
         return view

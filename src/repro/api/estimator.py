@@ -32,6 +32,7 @@ import dataclasses
 import warnings
 from typing import Optional
 
+from ..obs.trace import TRACER
 from ..systems import PimConfig, PimSystem, System
 from .dataset import PimDataset
 from .registry import FitResult, Workload, get_workload
@@ -123,26 +124,28 @@ class PimEstimator:
     # -- estimation protocol -------------------------------------------------
 
     def fit(self, X, y=None) -> "PimEstimator":
-        if isinstance(X, PimDataset):
-            if y is not None:
-                raise ValueError(
-                    "y must not be passed alongside a PimDataset — the "
-                    "dataset already holds its labels; rebuild it with "
-                    "System.put(X, y) to change them")
-            # a dataset is bound to the system holding its shards;
-            # training runs there.  Adopt it so the estimator's config
-            # and stats refer to the system that actually trained.
-            ds = X
-            self.system = ds.system
-            self.n_cores = self.system.config.n_cores
-        else:
-            ds = self.system.put(X, None if self.workload.unsupervised
-                                 else y)
-        spec = self.workload.spec(self.version, **self._params)
-        self.result_ = self.workload.fit(ds, spec)
-        for name, value in self.result_.attributes.items():
-            setattr(self, name, value)
-        return self
+        with TRACER.span("repro.fit", "fit", "fit",
+                         workload=self.workload.name, version=self.version):
+            if isinstance(X, PimDataset):
+                if y is not None:
+                    raise ValueError(
+                        "y must not be passed alongside a PimDataset — the "
+                        "dataset already holds its labels; rebuild it with "
+                        "System.put(X, y) to change them")
+                # a dataset is bound to the system holding its shards;
+                # training runs there.  Adopt it so the estimator's config
+                # and stats refer to the system that actually trained.
+                ds = X
+                self.system = ds.system
+                self.n_cores = self.system.config.n_cores
+            else:
+                ds = self.system.put(X, None if self.workload.unsupervised
+                                     else y)
+            spec = self.workload.spec(self.version, **self._params)
+            self.result_ = self.workload.fit(ds, spec)
+            for name, value in self.result_.attributes.items():
+                setattr(self, name, value)
+            return self
 
     def _fitted(self) -> FitResult:
         if self.result_ is None:

@@ -284,7 +284,7 @@ def fit_steps(dataset, cfg: Optional[KMeansConfig] = None,
         change between restarts, and every boundary of a restart drains
         (or is discarded) before the restart ends (DESIGN.md §14.1)."""
         def _snap():
-            arrays = {"C": np.asarray(C_v, np.float32)}
+            arrays = {"C": np.asarray(pim.read(C_v), np.float32)}
             meta = {"iters": int(it_total_v), "init": int(init),
                     "done": bool(done_v), "n_it": int(n_it_v),
                     "it_sched": int(it_sched_v),
@@ -358,22 +358,23 @@ def fit_steps(dataset, cfg: Optional[KMeansConfig] = None,
                 for bnd in drained:
                     final = bnd.carry
                     yield _drain(bnd)
-                    if bool(bnd.carry[1]):  # converged at this boundary
+                    if bool(pim.read(bnd.carry[1])):  # converged here
                         stop = True
                         break
             if not stop:
                 for bnd in pipe.flush():
                     final = bnd.carry
                     yield _drain(bnd)
-                    if bool(bnd.carry[1]):
+                    if bool(pim.read(bnd.carry[1])):
                         break
             if final is not None:
-                C = np.asarray(final[0], np.float32)
-                n_it = int(final[2])
+                C, n_it = pim.read((final[0], final[2]))
+                C = C.astype(np.float32)
+                n_it = int(n_it)
         else:
             while not done and n_it < cfg.max_iters:
                 Cq = pim.broadcast((_cast_centroids(C),))[0]
-                part = pim.map_reduce(assign_k, (Xs, valid), (Cq,))
+                part = pim.read(pim.map_reduce(assign_k, (Xs, valid), (Cq,)))
                 sums = np.asarray(part["sums"], np.float64)
                 counts = np.asarray(part["counts"], np.float64)
                 newC = np.where(counts[:, None] > 0,
@@ -385,8 +386,8 @@ def fit_steps(dataset, cfg: Optional[KMeansConfig] = None,
                 done = shift < cfg.tol
                 it_total += 1
                 yield ChunkTick(1, _snapshot)
-        part = pim.map_reduce(
-            inertia_k, (Xs, valid), (_cast_centroids(C),))
+        part = pim.read(pim.map_reduce(
+            inertia_k, (Xs, valid), (_cast_centroids(C),)))
         # inertia needs + ||x||^2 which the kernel includes; convert units
         inertia = float(part["inertia"]) * float(scale) ** 2
         if best is None or inertia < best.inertia:
@@ -395,7 +396,7 @@ def fit_steps(dataset, cfg: Optional[KMeansConfig] = None,
             if return_labels:
                 lbl = pim.map_elementwise(
                     labels_k, (Xs, valid), (_cast_centroids(C),))
-                best.labels = np.asarray(lbl).reshape(-1)[: n]
+                best.labels = pim.read(lbl).reshape(-1)[: n]
     return best
 
 

@@ -279,7 +279,10 @@ def fit_steps(dataset, cfg: Optional[GdConfig] = None,
     def record(it, wv, bv):
         if cfg.record_every and (it % cfg.record_every == 0
                                  or it == cfg.n_iters):
-            metric = eval_fn(np.asarray(wv), float(bv)) if eval_fn else None
+            metric = None
+            if eval_fn:
+                wh, bh = pim.read((wv, bv))
+                metric = eval_fn(wh, float(bh))
             history.append((it, metric))
 
     def _make_snapshot(wv, bv, sv, it, ra, rm):
@@ -289,9 +292,10 @@ def fit_steps(dataset, cfg: Optional[GdConfig] = None,
         captured per boundary (the rng pack eagerly at dispatch — the
         stream advances with the next chunk's draws)."""
         def _snap():
-            arrays = {"w": np.asarray(wv, np.float32),
-                      "b": np.asarray(bv, np.float32),
-                      "s": np.asarray(sv, np.float32)}
+            wh, bh, sh = pim.read((wv, bv, sv))
+            arrays = {"w": np.asarray(wh, np.float32),
+                      "b": np.asarray(bh, np.float32),
+                      "s": np.asarray(sh, np.float32)}
             meta = {"iters": int(it),
                     "history": [[int(i), None if m is None else float(m)]
                                 for i, m in history]}
@@ -375,6 +379,7 @@ def fit_steps(dataset, cfg: Optional[GdConfig] = None,
             it_done = it + 1
             record(it_done, w, b)
             yield ChunkTick(1, _snapshot)
+    w, b = pim.read((w, b))
     return GdResult(w=np.asarray(w, np.float32), b=float(b),
                     history=history, n_iters=cfg.n_iters)
 

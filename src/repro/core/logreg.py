@@ -221,7 +221,10 @@ def fit_steps(dataset, cfg: Optional[LogRegConfig] = None,
     def record(it, wv, bv):
         if cfg.record_every and (it % cfg.record_every == 0
                                  or it == cfg.n_iters):
-            metric = eval_fn(np.asarray(wv), float(bv)) if eval_fn else None
+            metric = None
+            if eval_fn:
+                wh, bh = pim.read((wv, bv))
+                metric = eval_fn(wh, float(bh))
             history.append((it, metric))
 
     def _make_snapshot(wv, bv, sv, it):
@@ -229,9 +232,10 @@ def fit_steps(dataset, cfg: Optional[LogRegConfig] = None,
         live carry races ahead of drained boundaries when pipelined —
         DESIGN.md §14.1)."""
         def _snap():
-            return {"arrays": {"w": np.asarray(wv, np.float32),
-                               "b": np.asarray(bv, np.float32),
-                               "s": np.asarray(sv, np.float32)},
+            wh, bh, sh = pim.read((wv, bv, sv))
+            return {"arrays": {"w": np.asarray(wh, np.float32),
+                               "b": np.asarray(bh, np.float32),
+                               "s": np.asarray(sh, np.float32)},
                     "meta": {"iters": int(it),
                              "history": [[int(i),
                                           None if m is None else float(m)]
@@ -274,6 +278,7 @@ def fit_steps(dataset, cfg: Optional[LogRegConfig] = None,
             it_done = it + 1
             record(it_done, w, b)
             yield ChunkTick(1, _snapshot)
+    w, b = pim.read((w, b))
     return GdResult(w=np.asarray(w, np.float32), b=float(b),
                     history=history, n_iters=cfg.n_iters)
 

@@ -100,6 +100,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         functools.partial(_flash_kernel, scale=scale, causal=causal,
                           n_kv=n_kv, bq=bq, bk=bk, q_offset=q_offset,
                           window=window),
+        name="mha",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda h, i, j: (h, i, 0)),

@@ -103,6 +103,7 @@ def gini_counts(x: jnp.ndarray, y: jnp.ndarray, leaf: jnp.ndarray,
     seg = (leaf * n_classes + y).reshape(1, n)
     counts, totals = pallas_call(
         _gini_kernel,
+        name="gini_split",
         grid=(n // bn,),
         in_specs=[
             pl.BlockSpec((f, bn), lambda i: (0, i)),

@@ -88,6 +88,7 @@ def kmeans_assign(x_q: jnp.ndarray, c_q: jnp.ndarray, *,
     assert n % bn == 0, (n, bn)
     labels, sums, counts = pallas_call(
         _kmeans_kernel,
+        name="kmeans_assign",
         grid=(n // bn,),
         in_specs=[
             pl.BlockSpec((f, bn), lambda i: (0, i)),

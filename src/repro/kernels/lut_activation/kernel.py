@@ -90,6 +90,7 @@ def lut_sigmoid_vmem(x_q: jnp.ndarray, table: jnp.ndarray, *,
     return pallas_call(
         functools.partial(_lut_sigmoid_kernel, n_entries=table.shape[0],
                           value_frac=value_frac),
+        name="lut_sigmoid",
         grid=(rows // br,),
         in_specs=[
             pl.BlockSpec((br, lanes), lambda i: (i, 0)),

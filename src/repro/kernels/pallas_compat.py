@@ -20,15 +20,19 @@ def vmem_scratch(shape, dtype):
     return pltpu.VMEM(tuple(shape), dtype)
 
 
-def pallas_call(kernel_fn, *, grid=None, in_specs=None, out_specs=None,
-                out_shape=None, scratch_shapes=None,
+def pallas_call(kernel_fn, *, name: str, grid=None, in_specs=None,
+                out_specs=None, out_shape=None, scratch_shapes=None,
                 dimension_semantics=None, interpret: bool = False):
     """``pl.pallas_call`` with the compiler parameters every kernel sets.
 
-    ``dimension_semantics`` is a plain tuple of strings that becomes a
-    ``pltpu.CompilerParams``; all other arguments pass through.
+    ``name`` is the kernel's dispatch op name (``fx_matvec``,
+    ``lut_sigmoid``, ...): the compiled kernel carries it, whatever
+    Python function wraps the call.  ``dimension_semantics`` is a plain
+    tuple of strings that becomes a ``pltpu.CompilerParams``; all other
+    arguments pass through.
     """
-    kwargs: dict = {"out_shape": out_shape, "interpret": interpret}
+    kwargs: dict = {"out_shape": out_shape, "interpret": interpret,
+                    "name": name}
     if grid is not None:
         kwargs["grid"] = grid
     if in_specs is not None:

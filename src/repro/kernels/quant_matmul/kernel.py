@@ -56,6 +56,7 @@ def int_matmul(a_q: jnp.ndarray, b_q: jnp.ndarray, *, bm: int = 128,
     grid = (m // bm, n // bn, n_k)
     return pallas_call(
         functools.partial(_quant_matmul_kernel, n_k=n_k),
+        name="int_matmul",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
@@ -96,6 +97,7 @@ def fx_matvec(x_q: jnp.ndarray, w_q: jnp.ndarray, *, frac_bits: int,
     assert n % bn == 0, (n, bn)
     out = pallas_call(
         functools.partial(_fx_matvec_kernel, frac_bits=frac_bits),
+        name="fx_matvec",
         grid=(n // bn,),
         in_specs=[
             pl.BlockSpec((f, bn), lambda i: (0, i)),

@@ -101,6 +101,7 @@ def emb_gather(table: jnp.ndarray, ids: jnp.ndarray, idx: jnp.ndarray,
     assert b % bb == 0 and r % br == 0, (b, bb, r, br)
     return pallas_call(
         _gather_kernel,
+        name="emb_gather",
         grid=(b // bb, r // br),
         in_specs=[
             pl.BlockSpec((br, d), lambda i, j: (j, 0)),
@@ -145,6 +146,7 @@ def emb_scatter_add(table: jnp.ndarray, ids: jnp.ndarray,
     assert r % br == 0, (r, br)
     return pallas_call(
         _scatter_kernel,
+        name="emb_scatter_add",
         grid=(r // br,),
         in_specs=[
             pl.BlockSpec((br, d), lambda i: (i, 0)),

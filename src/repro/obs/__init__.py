@@ -1,8 +1,10 @@
 """repro.obs — the unified telemetry layer (DESIGN.md §13).
 
-Zero-dependency observability for the whole runtime:
+Observability for the whole runtime, on nothing but the standard
+library and ``jax.profiler``:
 
-  :mod:`repro.obs.trace`         span tracer (ring buffer, global TRACER)
+  :mod:`repro.obs.trace`         span tracer (ring buffer and profiler
+                                 trace, global TRACER)
   :mod:`repro.obs.chrome_trace`  Chrome trace-event JSON export
   :mod:`repro.obs.metrics`       counters / gauges / histograms registry
   :mod:`repro.obs.format`        shared CLI table rendering
@@ -18,20 +20,19 @@ from __future__ import annotations
 import atexit
 import os
 
-from repro.obs.chrome_trace import (load_chrome_trace, summarize,
-                                    to_chrome_trace, track_names,
-                                    validate_chrome_trace,
+from repro.obs.chrome_trace import (load_chrome_trace, to_chrome_trace,
+                                    track_names, validate_chrome_trace,
                                     write_chrome_trace)
 from repro.obs.format import Column, format_bytes, format_ratio, render_table
 from repro.obs.metrics import (DRIFT_BUCKETS, Counter, Gauge, Histogram,
                                MetricsRegistry)
 from repro.obs.runmeta import run_meta, write_json
-from repro.obs.trace import TRACER, Tracer, counter, instant, span
+from repro.obs.trace import TRACER, Tracer
 
 __all__ = [
-    "TRACER", "Tracer", "span", "instant", "counter",
+    "TRACER", "Tracer",
     "to_chrome_trace", "write_chrome_trace", "validate_chrome_trace",
-    "load_chrome_trace", "track_names", "summarize",
+    "load_chrome_trace", "track_names",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "DRIFT_BUCKETS",
     "Column", "render_table", "format_bytes", "format_ratio",
     "run_meta", "write_json",

@@ -144,22 +144,3 @@ def _check_nesting(row, spans: List[dict]) -> None:
 def load_chrome_trace(path: str) -> dict:
     with open(path) as fh:
         return json.load(fh)
-
-
-def summarize(doc: dict) -> dict:
-    """Per-track event counts + span time (quick CLI sanity line)."""
-    names = {}
-    for ev in doc.get("traceEvents", []):
-        if ev.get("ph") == "M" and ev.get("name") == "thread_name":
-            names[(ev["pid"], ev["tid"])] = ev["args"]["name"]
-    out: dict = {}
-    for ev in doc.get("traceEvents", []):
-        if ev.get("ph") == "M":
-            continue
-        track = names.get((ev["pid"], ev["tid"]),
-                          f"{ev['pid']}:{ev['tid']}")
-        row = out.setdefault(track, {"events": 0, "span_us": 0.0})
-        row["events"] += 1
-        if ev["ph"] == "X":
-            row["span_us"] += ev.get("dur", 0.0)
-    return out

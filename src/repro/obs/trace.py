@@ -2,23 +2,33 @@
 
 One process-global :class:`Tracer` collects timing *events* — nestable
 spans, instant markers, and counter samples — into a thread-safe ring
-buffer.  Every execution layer is instrumented against it: ``System``
-kernel launches and fused chunks (systems/base.py), dataset shard
-transfers (api/dataset.py), model broadcasts (systems/pim.py),
-scheduler admission / gang-step chunks / elastic events
-(sched/scheduler.py), and allocator channel occupancy
-(sched/allocator.py).  The buffer renders to a Chrome trace-event file
-via :mod:`repro.obs.chrome_trace` (``pim_jobs --trace out.json`` or the
-``REPRO_TRACE`` environment variable).
+buffer.  The fit path is instrumented against it with a fixed span
+vocabulary (DESIGN.md §13.1): ``repro.fit`` (the estimator's fit),
+``repro.step`` (one trainer step), ``repro.launch`` (one kernel
+launch), ``repro.chunk`` (one fused ``StepProgram`` chunk),
+``repro.read`` (the host blocking on device results) and ``repro.view``
+(one dataset view materialised).  The scheduler adds its admission,
+gang-step chunk and elastic events (sched/scheduler.py), the allocator
+its channel occupancy (sched/allocator.py).
 
-Overhead contract (asserted by tests/test_obs.py): the tracer is
-**disabled by default** and a disabled call is one attribute check plus
-a constant return — no event dict, no timestamp, no lock.  Hot paths
-that would pay even for building a span *name* guard on
-``TRACER.enabled`` first (the ``_launch_span`` idiom in
-systems/base.py).  Enabled, each event is one ``perf_counter`` pair and
-one deque append; the ring buffer (default 200k events) bounds memory
-on long-running services by dropping the *oldest* events.
+Two sinks.  While ``enabled``, every event goes to the ring buffer,
+with its args, on the tracer's own clock; the buffer renders to a
+Chrome trace-event file via :mod:`repro.obs.chrome_trace` (``pim_jobs
+--trace out.json`` or the ``REPRO_TRACE`` environment variable).  While
+a JAX profiler session records (``jax.profiler.start_trace``), every
+span also opens a ``jax.profiler.TraceAnnotation`` of the span's
+constant name and no metadata, so the span lands in the profiler's
+trace on the same clock as the device's events.  Instants and counters
+go to the ring buffer only.
+
+Overhead contract (asserted by tests/test_obs.py): both sinks are off
+by default, and a span with both off costs one attribute check plus one
+``TraceAnnotation.is_enabled()`` call before it returns the shared
+no-op — no event dict, no timestamp, no lock.  Instants and counters
+cost the attribute check alone.  Enabled, each ring-buffer event is one
+``perf_counter`` pair and one deque append; the ring buffer (default
+200k events) bounds memory on long-running services by dropping the
+*oldest* events.
 
 Tracks: every event names a ``track`` — a free-form string rendered as
 its own timeline row.  The repo's taxonomy (DESIGN.md §13.2):
@@ -26,14 +36,15 @@ its own timeline row.  The repo's taxonomy (DESIGN.md §13.2):
   ``sched``             scheduler control flow (admission, defragment)
   ``target:<name>``     per-execution-System timeline of chunk spans
   ``job:<name>``        per-job timeline (one row per tenant)
-  ``system:<kind>``     kernel launches / transfers of one System kind
+  ``fit``               estimator fits and their trainer steps
+  ``system:<kind>``     launches, chunks, reads and views of one System
   ``channels:<name>``   per-memory-channel occupancy counters
 
-Timestamps are microseconds of ``time.perf_counter()`` since tracer
-construction (monotonic; wall-clock anchoring travels in the run
-metadata envelope, repro/obs/runmeta.py).  Spans measure *host-visible*
-time: under jax async dispatch a launch span covers dispatch plus any
-blocking the call itself performs.
+Ring-buffer timestamps are microseconds of ``time.perf_counter()``
+since tracer construction (monotonic; wall-clock anchoring travels in
+the run metadata envelope, repro/obs/runmeta.py).  Spans measure
+*host-visible* time: under jax async dispatch a launch span covers
+dispatch plus any blocking the call itself performs.
 """
 from __future__ import annotations
 
@@ -41,6 +52,8 @@ import threading
 import time
 from collections import deque
 from typing import Optional
+
+from jax.profiler import TraceAnnotation
 
 #: default ring-buffer capacity (events); ~100 B/event -> ~20 MB ceiling
 DEFAULT_CAPACITY = 200_000
@@ -62,9 +75,11 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """An open span; appends one complete ("X") event on exit."""
+    """An open span; appends one complete ("X") event on exit, and
+    mirrors itself into the profiler's trace while a session records."""
 
-    __slots__ = ("_tracer", "_name", "_track", "_cat", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_track", "_cat", "_args", "_t0",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, track: str, cat: str,
                  args: Optional[dict]):
@@ -73,8 +88,12 @@ class _Span:
         self._track = track
         self._cat = cat
         self._args = args
+        self._annotation = (TraceAnnotation(name)
+                            if TraceAnnotation.is_enabled() else None)
 
     def __enter__(self):
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self._t0 = self._tracer.now_us()
         return self
 
@@ -84,6 +103,8 @@ class _Span:
                    "track": self._track, "ts": self._t0,
                    "dur": t.now_us() - self._t0,
                    "args": self._args or {}})
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
         return False
 
 
@@ -134,13 +155,19 @@ class Tracer:
              **args):
         """Context manager timing a nested span on ``track``.
 
-        Disabled: returns the shared no-op immediately.  Spans on one
-        track must nest (the exporter validates containment) — which
-        they do by construction when emitted from ``with`` blocks on a
-        single thread per track."""
-        if not self.enabled:
-            return NULL_SPAN
-        return _Span(self, name, track, cat, args or None)
+        Disabled with no profiler session recording: returns the shared
+        no-op.  Disabled while one records: the profiler's
+        ``TraceAnnotation(name)`` alone — ``name`` must then be a
+        constant, and per-call detail goes into ``args``, which only
+        the ring buffer keeps.  Spans on one track must nest (the
+        exporter validates containment) — which they do by construction
+        when emitted from ``with`` blocks on a single thread per
+        track."""
+        if self.enabled:
+            return _Span(self, name, track, cat, args or None)
+        if TraceAnnotation.is_enabled():
+            return TraceAnnotation(name)
+        return NULL_SPAN
 
     def instant(self, name: str, track: str = "main",
                 cat: str = "default", **args) -> None:
@@ -171,16 +198,3 @@ class Tracer:
 
 #: the process-global tracer every instrumentation site emits to
 TRACER = Tracer()
-
-
-def span(name: str, track: str = "main", cat: str = "default", **args):
-    return TRACER.span(name, track, cat, **args)
-
-
-def instant(name: str, track: str = "main", cat: str = "default",
-            **args) -> None:
-    TRACER.instant(name, track, cat, **args)
-
-
-def counter(name: str, value: float, track: str = "counters") -> None:
-    TRACER.counter(name, value, track)

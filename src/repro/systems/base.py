@@ -47,7 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..obs.trace import NULL_SPAN, TRACER
+from ..obs.trace import TRACER
 
 
 class ReduceVia(enum.Enum):
@@ -88,7 +88,10 @@ class TransferStats:
     host blocks on device results (one per ``map_reduce``/
     ``map_reduce_custom`` call, one per fused :class:`StepProgram`
     chunk).  The step-fusion engine's whole point is that a k-step chunk
-    costs ONE sync instead of k (DESIGN.md §9).
+    costs ONE sync instead of k (DESIGN.md §9).  It is counted by
+    launch arithmetic, not where the host waits: ``device_reads``
+    counts the calls of :meth:`System.read`, the one place the host
+    blocks on device results and copies them back.
 
     ``snapshot()``/``delta(snapshot)`` make the counters attributable
     when several jobs share one system: snapshot before the job, delta
@@ -103,6 +106,7 @@ class TransferStats:
     shard_bytes: int = 0
     kernel_launches: int = 0
     host_syncs: int = 0
+    device_reads: int = 0
     #: processor-centric targets only: bytes the training hot loop
     #: streams from DRAM (HostSystem / ModeledGpuSystem); 0 on PIM.
     dram_bytes: int = 0
@@ -201,10 +205,14 @@ def run_steps(gen):
     (one host-orchestrated iteration per ``next()``) so the job
     scheduler can gang-step many fits concurrently; ``fit`` is simply
     this drain loop.  The fitted result travels on ``StopIteration``.
+    Each ``next()`` is one ``repro.step`` span: one iteration when the
+    trainer runs serially, one chunk when it fuses, and a last step
+    that reads back the result.
     """
     while True:
         try:
-            next(gen)
+            with TRACER.span("repro.step", "fit", "step"):
+                next(gen)
         except StopIteration as stop:
             return stop.value
 
@@ -330,6 +338,13 @@ class ReduceStrategy:
         return self.name
 
 
+def _kernel_name(kkey) -> str:
+    """A kernel's name for trace args: its registered name, or the
+    callable's own."""
+    return kkey[1] if kkey[0] == "named" else getattr(kkey[1], "__name__",
+                                                      "fn")
+
+
 def _leaf_bytes(v) -> int:
     """nbytes of an array OR an abstract value (ShapeDtypeStruct)."""
     nb = getattr(v, "nbytes", None)
@@ -385,7 +400,7 @@ class HostReduce(ReduceStrategy):
         return _tree_bytes(out)  # stacked (n_cores, ...) leaves
 
     def finalize(self, system, out):
-        return _host_sum(jax.device_get(out))
+        return _host_sum(system.read(out))
 
 
 class HierarchicalReduce(ReduceStrategy):
@@ -485,7 +500,7 @@ class HierarchicalReduce(ReduceStrategy):
         # target there is no host link, and the counter must stay 0.
         if self._groups(system.config.n_cores):
             system._charge_inter_core(_tree_bytes(out))
-        return _host_sum(jax.device_get(out))
+        return _host_sum(system.read(out))
 
 
 _STRATEGIES: dict[str, Callable[[], ReduceStrategy]] = {
@@ -551,18 +566,6 @@ class System:
         #: trace timeline for this system's kernel launches (precomputed
         #: so the hot path never builds the string — DESIGN.md §13.2)
         self._trace_track = f"system:{self.kind}"
-
-    def _launch_span(self, op: str, kkey):
-        """Span covering one kernel launch on the system's trace track.
-
-        The overhead contract (repro.obs.trace): when tracing is off
-        this returns the shared no-op before any span *name* is built —
-        the f-string below never runs on the untraced hot path."""
-        if not TRACER.enabled:
-            return NULL_SPAN
-        name = (kkey[1] if kkey[0] == "named"
-                else getattr(kkey[1], "__name__", "fn"))
-        return TRACER.span(f"{op}:{name}", self._trace_track, "launch")
 
     # -- identity ------------------------------------------------------------
 
@@ -710,6 +713,14 @@ class System:
 
     # -- execution ------------------------------------------------------------
 
+    def read(self, tree):
+        """Wait for device results and copy them to the host as numpy:
+        the one place the host blocks on the device.  Each call is one
+        ``repro.read`` span and one ``device_reads``."""
+        self.stats.device_reads += 1
+        with TRACER.span("repro.read", self._trace_track, "read"):
+            return jax.tree_util.tree_map(np.asarray, jax.device_get(tree))
+
     def map_reduce(self, kernel, sharded: tuple, replicated: tuple,
                    strategy: StrategyLike = None):
         """Run ``kernel(*shard_args, *replicated)`` on every shard and
@@ -731,7 +742,8 @@ class System:
         self.stats.kernel_launches += 1
         self.stats.host_syncs += 1
         self._charge_launch_operands(sharded, replicated)
-        with self._launch_span("map_reduce", kkey):
+        with TRACER.span("repro.launch", self._trace_track, "launch",
+                         op="map_reduce", kernel=_kernel_name(kkey)):
             out = step(tuple(sharded), tuple(replicated))
         self._record_execution(key, step, (tuple(sharded),
                                            tuple(replicated)))
@@ -759,7 +771,8 @@ class System:
         self.stats.kernel_launches += 1
         self.stats.host_syncs += 1
         self._charge_launch_operands(sharded, replicated)
-        with self._launch_span("custom", kkey):
+        with TRACER.span("repro.launch", self._trace_track, "launch",
+                         op="custom", kernel=_kernel_name(kkey)):
             out = step(tuple(sharded), tuple(replicated))
         self._record_execution(key, step, (tuple(sharded),
                                            tuple(replicated)))
@@ -779,7 +792,8 @@ class System:
             self._jit_cache[key] = step
         self.stats.kernel_launches += 1
         self._charge_elementwise(sharded, replicated)
-        with self._launch_span("elem", kkey):
+        with TRACER.span("repro.launch", self._trace_track, "launch",
+                         op="elem", kernel=_kernel_name(kkey)):
             out = step(tuple(sharded), tuple(replicated))
         self._record_execution(key, step, (tuple(sharded),
                                            tuple(replicated)))
@@ -960,11 +974,8 @@ class StepProgram:
         self.system._charge_chunk(
             carry, sharded, self._reduced_shape(carry, sharded, xs),
             self.strategy, k)
-        if TRACER.enabled:
-            with TRACER.span(f"chunk:{self.name}",
-                             self.system._trace_track, "launch", k=k):
-                carry, outs = chunk(carry, sharded, xs)
-        else:
+        with TRACER.span("repro.chunk", self.system._trace_track, "launch",
+                         program=self.name, k=k):
             carry, outs = chunk(carry, sharded, xs)
         self.system._record_execution(key, chunk, (carry, sharded, xs),
                                       k=k)

@@ -53,7 +53,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.quantization import storage_bytes
-from ..obs.trace import TRACER
 from .base import ReduceVia, System
 from .topology import (DEFAULT_RANKS_PER_CHANNEL, DPU_FREQ_HZ,
                        DPU_MRAM_BYTES_PER_CYCLE, DPU_OP_CYCLES,
@@ -153,12 +152,12 @@ class PimSystem(System):
         return mask
 
     def broadcast(self, tree: Any) -> Any:
-        """Host -> all cores broadcast of model state (counted per core)."""
-        nbytes = sum(np.asarray(v).nbytes for v in jax.tree_util.tree_leaves(tree))
+        """Host -> all cores broadcast of model state (counted per core).
+        The byte count reads the state back to the host: one
+        :meth:`System.read` per broadcast."""
+        nbytes = sum(v.nbytes for v in
+                     jax.tree_util.tree_leaves(self.read(tree)))
         self.stats.cpu_to_pim += nbytes * self.config.n_cores
-        if TRACER.enabled:
-            TRACER.instant("broadcast", self._trace_track, "transfer",
-                           bytes=nbytes * self.config.n_cores)
         if self._mesh is not None:
             tree = jax.device_put(
                 tree, NamedSharding(self._mesh, P()))  # replicated

@@ -86,6 +86,24 @@ def test_tracer_records_spans_instants_counters():
     assert events[3]["args"] == {"value": 0.5}
 
 
+def test_span_mirrors_into_the_profiler_only_while_it_records(tmp_path):
+    import jax
+    from jax.profiler import TraceAnnotation
+    t = Tracer()
+    assert not TraceAnnotation.is_enabled()
+    assert t.span("repro.step", track="fit") is NULL_SPAN
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        span = t.span("repro.step", track="fit", k=1)
+        assert isinstance(span, TraceAnnotation)
+        with span:
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    assert len(t) == 0                 # the ring buffer stayed off
+    assert t.span("repro.step", track="fit") is NULL_SPAN
+
+
 def test_tracer_ring_buffer_drops_oldest():
     t = Tracer(capacity=4)
     t.enable()
@@ -383,8 +401,7 @@ def test_scheduler_trace_has_expected_tracks_and_spans(tracer):
     names = {e["name"] for e in body}
     assert "chunk" in names and "admit" in names
     assert any(n.startswith("channel") for n in names)   # occupancy
-    assert any(n.startswith("map_reduce:") or n.startswith("chunk:")
-               for n in names)                            # launch spans
+    assert "repro.launch" in names or "repro.chunk" in names  # launches
 
 
 def test_preempt_resume_instants_in_trace(tracer):
@@ -577,3 +594,73 @@ def test_repro_trace_env_var_exports_on_exit(tmp_path):
     doc = load_chrome_trace(trace_path)
     validate_chrome_trace(doc)
     assert track_names(doc) == {"t"}
+
+
+# ---------------------------------------------------------------------------
+# The fit path's spans in the profiler's trace, and the read counter.
+# ---------------------------------------------------------------------------
+
+def _log_dataset():
+    X, y, _ = make_linear_dataset(512, 6, seed=0)
+    y = (y > np.median(y)).astype(np.float32)
+    return make_system("pim", n_cores=8).put(X, y)
+
+
+def _log_fit(ds, n_iters, fuse_steps=1):
+    from repro.api import make_estimator
+    return make_estimator("logreg", version="int32_lut_wram",
+                          system=ds.system, n_iters=n_iters,
+                          fuse_steps=fuse_steps).fit(ds)
+
+
+def _inside(outer, inner):
+    return [[(s, e) for s, e in inner if s0 <= s and e <= e0]
+            for s0, e0 in outer]
+
+
+def test_profiler_trace_holds_the_fit_path_spans(tmp_path):
+    import jax
+    root = os.path.join(os.path.dirname(__file__), os.pardir)
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench import trace as xtrace
+    n_iters = 4
+    ds = _log_dataset()
+    _log_fit(ds, n_iters)                  # compiles outside the trace
+    assert not TRACER.enabled
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("bench.window"):
+            _log_fit(ds, n_iters)
+    finally:
+        jax.profiler.stop_trace()
+    tr = xtrace.load(str(tmp_path), "bench.window")
+    hs, he, hn = tr.host
+
+    def spans(name):
+        return sorted(zip(hs[hn == name].tolist(), he[hn == name].tolist()))
+    fits, steps = spans("repro.fit"), spans("repro.step")
+    launches, reads = spans("repro.launch"), spans("repro.read")
+    assert len(fits) == 1 and len(steps) == n_iters + 1
+    assert _inside(fits, steps) == [steps]
+    # one launch a step, each step but the last reading the broadcast
+    # state back; the last step reads the result
+    assert [len(x) for x in _inside(steps, launches)] == [1] * n_iters + [0]
+    assert [len(x) for x in _inside(steps, reads)] == [1] * (n_iters + 1)
+    assert len(launches) == n_iters and len(reads) == n_iters + 1
+    assert not len(TRACER)                 # the ring buffer stayed off
+
+
+@pytest.mark.parametrize("n_iters", [4, 8])
+def test_device_reads_count_the_reads_that_block(n_iters):
+    ds = _log_dataset()
+    before = ds.system.stats.snapshot()
+    _log_fit(ds, n_iters)
+    serial = ds.system.stats.delta(before)
+    # the broadcast's byte count each iteration, and the result
+    assert serial.device_reads == n_iters + 1
+    assert serial.host_syncs == n_iters
+    before = ds.system.stats.snapshot()
+    _log_fit(ds, n_iters, fuse_steps=n_iters)
+    fused = ds.system.stats.delta(before)
+    assert fused.device_reads == 1 and fused.host_syncs == 1

@@ -17,30 +17,28 @@ import jax.numpy as jnp
 
 from repro.core.lut import SigmoidLut
 from ..dispatch import legacy_launch, register_op
-from .kernel import lut_sigmoid_vmem
+from .kernel import BLOCK_ROWS, LANES, lut_sigmoid_vmem, row_blocks
 from .ref import lut_sigmoid_ref
 
 
 def _sigmoid_pallas(x_q: jnp.ndarray, lut: SigmoidLut, *,
                     interpret: bool = True,
-                    block_rows: int = 256) -> jnp.ndarray:
-    """VMEM-kernel path: flatten, pad to a (rows, 128) grid, slice back."""
+                    block_rows: int = BLOCK_ROWS) -> jnp.ndarray:
+    """VMEM-kernel path: flatten, pad to blocks of (br, 128) (see
+    :func:`row_blocks`), slice back."""
     shape = x_q.shape
     flat = x_q.reshape(-1)
-    lanes = 128
     n = flat.shape[0]
-    rows = -(-n // lanes)
-    br = min(block_rows, max(rows, 1))
-    pad_rows = -(-rows // br) * br
-    padded = jnp.zeros((pad_rows * lanes,), x_q.dtype).at[:n].set(flat)
-    out = lut_sigmoid_vmem(padded.reshape(pad_rows, lanes), lut.table,
+    blocks, br = row_blocks(max(-(-n // LANES), 1), block_rows)
+    padded = jnp.pad(flat, (0, blocks * br * LANES - n))
+    out = lut_sigmoid_vmem(padded.reshape(blocks * br, LANES), lut.table,
                            value_frac=lut.value_frac, block_rows=br,
                            interpret=interpret)
     return out.reshape(-1)[:n].reshape(shape)
 
 
 def _sigmoid_ref(x_q: jnp.ndarray, lut: SigmoidLut, *,
-                 block_rows: int = 256) -> jnp.ndarray:
+                 block_rows: int = BLOCK_ROWS) -> jnp.ndarray:
     del block_rows  # jnp oracle needs no tiling
     return lut_sigmoid_ref(x_q, lut.table, lut.value_frac)
 
@@ -48,7 +46,7 @@ def _sigmoid_ref(x_q: jnp.ndarray, lut: SigmoidLut, *,
 def lut_sigmoid(x_q: jnp.ndarray, lut: SigmoidLut, *,
                 placement: str = "vmem", backend=None,
                 use_pallas: bool = None, interpret: bool = None,
-                block_rows: int = 256) -> jnp.ndarray:
+                block_rows: int = BLOCK_ROWS) -> jnp.ndarray:
     """Fixed-point sigmoid via LUT.  x_q int32 Q(lut.frac_bits), any shape.
 
     ``placement="hbm"`` forces the XLA gather (MRAM variant); otherwise

@@ -4,11 +4,12 @@
 grids + randomized draws per cell — see also tests/test_property.py.)
 """
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.core.fixed_point import to_fixed
-from repro.core.lut import build_sigmoid_lut
+from repro.core.lut import SigmoidLut, build_sigmoid_lut
 
 # ---------------------------------------------------------------------------
 # quant_matmul
@@ -69,6 +70,7 @@ def test_quant_dense_accuracy(dtype):
 # ---------------------------------------------------------------------------
 # lut_activation
 # ---------------------------------------------------------------------------
+from repro.kernels.dispatch import KernelBackend
 from repro.kernels.lut_activation.ops import lut_sigmoid
 from repro.kernels.lut_activation.ref import lut_sigmoid_ref
 
@@ -83,6 +85,54 @@ def test_lut_sigmoid_kernel_matches_ref(shape, frac_bits):
     out = lut_sigmoid(xq, lut, placement="vmem")
     ref = lut_sigmoid_ref(xq, lut.table, lut.value_frac)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+@pytest.mark.parametrize("frac_bits", [8, 10])
+def test_lut_sigmoid_every_index_and_past_boundary(frac_bits):
+    """Bit for bit on every table index and its negation (each table
+    row, each lane of the in-register gather), past the boundary, and
+    at the largest magnitudes int32 holds."""
+    lut = build_sigmoid_lut(boundary=20, frac_bits=frac_bits)
+    n = lut.table.shape[0]
+    past = np.array([n, n + 1, n + 127, 2 * n, 10 ** 6, 10 ** 9,
+                     2 ** 31 - 2, 2 ** 31 - 1])
+    mags = np.concatenate([np.arange(n), past])
+    xq = jnp.asarray(np.concatenate([mags, -mags]), jnp.int32)
+    out = lut_sigmoid(xq, lut, backend=KernelBackend.PALLAS_INTERPRET)
+    np.testing.assert_array_equal(
+        np.asarray(out), np.asarray(lut_sigmoid_ref(xq, lut.table,
+                                                    lut.value_frac)))
+
+
+def test_lut_sigmoid_any_int16_table():
+    """Two entries share a 32-bit word in the kernel's table: any int16
+    entry, the extremes included, comes back sign-extended, and a table
+    whose length is no multiple of 256 reads no padding."""
+    rng = np.random.RandomState(7)
+    table = rng.randint(-2 ** 15, 2 ** 15, 3 * 256 + 77).astype(np.int16)
+    table[:4] = [-2 ** 15, 2 ** 15 - 1, -1, 0]
+    lut = SigmoidLut(jnp.asarray(table), frac_bits=6, boundary=13,
+                     value_frac=15)
+    mags = np.arange(table.size + 200)
+    xq = jnp.asarray(np.concatenate([mags, -mags]), jnp.int32)
+    out = lut_sigmoid(xq, lut, backend=KernelBackend.PALLAS_INTERPRET)
+    np.testing.assert_array_equal(
+        np.asarray(out), np.asarray(lut_sigmoid_ref(xq, lut.table,
+                                                    lut.value_frac)))
+
+
+def test_lut_sigmoid_ragged_per_core_vmapped():
+    """The SUSY per-core length (78,125 logits: 611 rows of 128, padded
+    to 616) under ``vmap`` over a cores axis, as ``System`` calls it."""
+    lut = build_sigmoid_lut()
+    rng = np.random.RandomState(78125)
+    xq = jnp.asarray(rng.randint(-(25 << 10), 25 << 10, (2, 78_125)),
+                     jnp.int32)
+    out = jax.vmap(lambda z: lut_sigmoid(
+        z, lut, backend=KernelBackend.PALLAS_INTERPRET))(xq)
+    np.testing.assert_array_equal(
+        np.asarray(out), np.asarray(lut_sigmoid_ref(xq, lut.table,
+                                                    lut.value_frac)))
 
 
 def test_lut_sigmoid_placements_identical():

@@ -7,6 +7,8 @@ pipeline emitter inserts automatically).  These are *static* checks on
 the BlockSpec arithmetic — the structural analogue of the paper's WRAM
 budget argument (the 40 KB LUT in a 64 KB scratchpad, Fig. 4).
 """
+import jax.numpy as jnp
+
 VMEM_BUDGET = 16 * 2 ** 20
 DBL = 2  # double buffering factor on streamed blocks
 
@@ -46,8 +48,28 @@ def test_gini_split_vmem():
 
 def test_lut_sigmoid_vmem():
     """The paper's own budget argument: the 40 KB sigmoid table plus a
-    streamed activation block fits any scratchpad tier."""
-    table = 20 * 1024 * 2                         # = paper's 40 KB LUT
-    block = DBL * 256 * 128 * 4                   # int32 activation tile
-    assert table + block + 256 * 128 * 4 < VMEM_BUDGET
+    streamed activation block fits any scratchpad tier.  On the TPU the
+    table travels as int32 words of two entries for the lane gather (80
+    x 128 words: the same 40 KB), pinned; the logits stream in blocks of
+    at most BLOCK_ROWS rows, in and out, each double-buffered."""
+    from repro.core.lut import build_sigmoid_lut
+    from repro.kernels.lut_activation.kernel import (BLOCK_ROWS, LANES,
+                                                     row_blocks,
+                                                     table_words)
+    lut = build_sigmoid_lut(boundary=20, frac_bits=10)
+    table = lut.nbytes                            # = paper's 40 KB LUT
     assert table == 40 * 1024
+    tab = table_words(lut.table)
+    assert tab.shape == (80, LANES) and tab.dtype == jnp.int32
+    assert tab.nbytes == table
+    assert table_words(build_sigmoid_lut(frac_bits=8).table).shape == (
+        20, LANES)
+    pinned = DBL * tab.nbytes                     # the pipeline keeps two
+    block = DBL * 2 * BLOCK_ROWS * LANES * 4      # int32 in + out blocks
+    assert pinned + block < VMEM_BUDGET
+    assert pinned + block < 4 * 2 ** 20
+    # the SUSY (78,125) and Higgs (171,875) per-core logits: padded to a
+    # multiple of 8 rows, never to a fixed block
+    assert row_blocks(611) == (1, 616)
+    assert row_blocks(1343) == (2, 672)
+    assert row_blocks(611, block_rows=256) == (3, 208)

@@ -73,9 +73,20 @@ def fx_sum(t, axis=None):
     fewer than 2^23 rows and the summed ``|t|`` stays below 2^39, and
     pairs from many shards add element-wise without overflow — still
     integers, so every reduce order gives the same pair."""
-    hi = jnp.sum(t >> 8, axis=axis)
-    lo = jnp.sum(t & 255, axis=axis)
+    return fx_pair(jnp.sum(t >> 8, axis=axis), jnp.sum(t & 255, axis=axis))
+
+
+def fx_pair(hi, lo):
+    """The :func:`fx_sum` pair of a high-byte sum ``hi`` and a low-byte
+    sum ``lo``: the low sum's excess over 255 carried into ``hi``."""
     return jnp.stack([hi + (lo >> 8), lo & 255], axis=-1)
+
+
+def fx_pair_value(pair) -> np.ndarray:
+    """Exact float64 value of an integer :func:`fx_sum` pair, on the
+    host (float64 holds every sum below 2^53)."""
+    p = np.asarray(pair, np.float64)
+    return p[..., 0] * 256 + p[..., 1]
 
 
 def from_fixed_sum(pair, frac_bits: int):

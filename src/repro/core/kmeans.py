@@ -12,11 +12,15 @@ convergence.
 
 Numerics adaptation (DESIGN.md §2): UPMEM accumulates distances/sums in
 int64; TPUs have no fast int64, so we quantize coordinates to +-2047
-(12-bit range stored in int16) which makes the int32 distance and
-coordinate-sum accumulations *exact* for up to 2^9 features and ~2^19
-points per cluster per core — far beyond the evaluated sizes.  The paper's
-own quantization (+-32767) exists to avoid the identical overflow problem
-on the DPU; quality parity is preserved (ARI ~ 0.999 vs float CPU, §5.1.4).
+(12-bit range stored in int16), which keeps the int32 distances exact for
+up to 2^9 features.  A coordinate sum does not fit one int32: one cluster
+of more than 2^31 / 2047 (about 1.05M) rows near the range limit wraps,
+and at the Higgs shape (11M rows, K=16) a cluster often holds more.  So
+each core returns its sums as ``fx_sum`` pairs (``core/fixed_point.py``:
+``hi * 256 + lo``), the cores' pairs add element-wise, and the host (or
+the fused update) turns the reduced pair into a float.  The paper's own
+quantization (+-32767) exists to avoid the same overflow problem on the
+DPU; quality parity is preserved (ARI ~ 0.999 vs float CPU, §5.1.4).
 """
 from __future__ import annotations
 
@@ -29,8 +33,10 @@ import numpy as np
 
 from ..elastic.state import pack_rng, unpack_rng
 from ..kernels import dispatch
+from ..obs import TRACER
 from ..systems import (ChunkPipeline, ChunkTick, System, chunk_schedule,
                        run_steps)
+from .fixed_point import from_fixed_sum, fx_pair_value
 from .metrics import frobenius_shift
 
 # 12-bit symmetric range stored in int16 (see docstring).  The quantizing
@@ -95,11 +101,12 @@ def _assign_kernel_factory(k: int, backend=None, quantized: bool = True):
     distance + one-hot accumulation — no quantization, native float
     matmul, the paper's sklearn-style hot loop.
 
-    Neither path has a validity-mask concept, so padding is corrected
-    here: shard padding rows are all-zero vectors (see
-    ``PimSystem.shard_rows``), which contribute nothing to ``sums`` and
-    exactly one spurious count at their assigned label — subtracted via
-    a masked one-hot.
+    The int16 version's ``sums`` are int32 ``fx_sum`` pairs ``(K, F,
+    2)``, the fp32 version's plain float32 ``(K, F)``.  Neither path has
+    a validity-mask concept, so padding is corrected here: shard padding
+    rows are all-zero vectors (see ``PimSystem.shard_rows``), which
+    contribute nothing to ``sums`` and exactly one spurious count at
+    their assigned label — subtracted via a masked one-hot.
     """
     be = dispatch.resolve_backend(backend)
 
@@ -177,7 +184,8 @@ def _make_lloyd_step_fns(cfg: KMeansConfig):
 
     def update(carry, reduced):
         C, done, n_it = carry
-        sums = jnp.asarray(reduced["sums"], jnp.float32)
+        sums = (from_fixed_sum(reduced["sums"], 0) if quantized
+                else jnp.asarray(reduced["sums"], jnp.float32))
         counts = jnp.asarray(reduced["counts"], jnp.float32)
         newC = jnp.where(counts[:, None] > 0,
                          sums / jnp.maximum(counts[:, None], 1.0), C)
@@ -317,8 +325,9 @@ def fit_steps(dataset, cfg: Optional[KMeansConfig] = None,
         else:
             # host picks random points as initial centroids (paper:
             # random init)
-            idx = rng.choice(n, size=cfg.k, replace=False)
-            C = Xq_np[idx].astype(np.float32)           # quantized units
+            with TRACER.span("repro.init", "fit", "step"):
+                idx = rng.choice(n, size=cfg.k, replace=False)
+                C = Xq_np[idx].astype(np.float32)       # quantized units
             done = False
             n_it = 0
             it_sched = 0
@@ -375,7 +384,8 @@ def fit_steps(dataset, cfg: Optional[KMeansConfig] = None,
             while not done and n_it < cfg.max_iters:
                 Cq = pim.broadcast((_cast_centroids(C),))[0]
                 part = pim.read(pim.map_reduce(assign_k, (Xs, valid), (Cq,)))
-                sums = np.asarray(part["sums"], np.float64)
+                sums = (fx_pair_value(part["sums"]) if quantized
+                        else np.asarray(part["sums"], np.float64))
                 counts = np.asarray(part["counts"], np.float64)
                 newC = np.where(counts[:, None] > 0,
                                 sums / np.maximum(counts[:, None], 1), C)
@@ -386,17 +396,18 @@ def fit_steps(dataset, cfg: Optional[KMeansConfig] = None,
                 done = shift < cfg.tol
                 it_total += 1
                 yield ChunkTick(1, _snapshot)
-        part = pim.read(pim.map_reduce(
-            inertia_k, (Xs, valid), (_cast_centroids(C),)))
-        # inertia needs + ||x||^2 which the kernel includes; convert units
-        inertia = float(part["inertia"]) * float(scale) ** 2
-        if best is None or inertia < best.inertia:
-            best = KMeansResult(centroids=C * scale, inertia=inertia,
-                                n_iters=n_it)
-            if return_labels:
-                lbl = pim.map_elementwise(
-                    labels_k, (Xs, valid), (_cast_centroids(C),))
-                best.labels = pim.read(lbl).reshape(-1)[: n]
+        with TRACER.span("repro.finish", "fit", "step"):
+            part = pim.read(pim.map_reduce(
+                inertia_k, (Xs, valid), (_cast_centroids(C),)))
+            # inertia needs + ||x||^2 which the kernel includes; convert units
+            inertia = float(part["inertia"]) * float(scale) ** 2
+            if best is None or inertia < best.inertia:
+                best = KMeansResult(centroids=C * scale, inertia=inertia,
+                                    n_iters=n_it)
+                if return_labels:
+                    lbl = pim.map_elementwise(
+                        labels_k, (Xs, valid), (_cast_centroids(C),))
+                    best.labels = pim.read(lbl).reshape(-1)[: n]
     return best
 
 

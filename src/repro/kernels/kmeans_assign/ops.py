@@ -19,7 +19,8 @@ def _assign_pallas(x_q: jnp.ndarray, c_q: jnp.ndarray, *,
     """Kernel path: pads N to a block multiple, runs the kernel, and
     corrects the padding's contribution (padding rows are zeros -> they
     all land in the one cluster minimizing -2*0.c + ||c||^2, contribute
-    zero to ``sums``, and are subtracted from that cluster's count)."""
+    zero to both bytes of ``sums``, and are subtracted from that
+    cluster's count)."""
     n = x_q.shape[0]
     bn = min(block_n, max(n, 8))
     n_pad = -(-n // bn) * bn
@@ -42,12 +43,14 @@ def assign_and_accumulate(x_q: jnp.ndarray, c_q: jnp.ndarray, *,
                           backend=None, use_pallas: bool = None,
                           interpret: bool = None, block_n: int = 1024):
     """x_q int16 [N, F]; c_q int16 [K, F] ->
-    (labels int32 [N], sums int32 [K, F], counts int32 [K]).
+    (labels int32 [N], sums int32 [K, F, 2], counts int32 [K]).
 
-    ``backend`` picks the implementation (None = auto-select).  The
-    legacy ``use_pallas``/``interpret`` flags keep their meaning when
-    set explicitly; leaving everything unset now auto-selects
-    (``jnp_ref`` off-TPU — the old default was the interpret kernel).
+    ``sums`` is an ``fx_sum`` pair (``core/fixed_point.py``) worth
+    ``sums[..., 0] * 256 + sums[..., 1]``.  ``backend`` picks the
+    implementation (None = auto-select).  The legacy
+    ``use_pallas``/``interpret`` flags keep their meaning when set
+    explicitly; leaving everything unset now auto-selects (``jnp_ref``
+    off-TPU — the old default was the interpret kernel).
     """
     return legacy_launch("kmeans_assign", x_q, c_q, backend=backend,
                          use_pallas=use_pallas, interpret=interpret,

@@ -6,8 +6,10 @@ buffer.  The fit path is instrumented against it with a fixed span
 vocabulary (DESIGN.md §13.1): ``repro.fit`` (the estimator's fit),
 ``repro.step`` (one trainer step), ``repro.launch`` (one kernel
 launch), ``repro.chunk`` (one fused ``StepProgram`` chunk),
-``repro.read`` (the host blocking on device results) and ``repro.view``
-(one dataset view materialised).  The scheduler adds its admission,
+``repro.read`` (the host blocking on device results), ``repro.view``
+(one dataset view materialised), and K-Means' ``repro.init`` (a
+restart's init draw) and ``repro.finish`` (its inertia and labels
+passes).  The scheduler adds its admission,
 gang-step chunk and elastic events (sched/scheduler.py), the allocator
 its channel occupancy (sched/allocator.py).
 

@@ -476,7 +476,8 @@ class HierarchicalReduce(ReduceStrategy):
     def device_reduce_full(self, partials):
         """In a fused scan the rank partials combine on fabric instead of
         on the host (int32 accumulation — exact whenever the flat fabric
-        sum is, which the GD/KME value ranges guarantee)."""
+        sum is: the GD gradients and the KME cluster sums travel as
+        ``fx_sum`` pairs, whose elements stay far inside int32)."""
         return jax.tree_util.tree_map(
             lambda v: jnp.sum(v, axis=0), self.device_reduce(partials))
 

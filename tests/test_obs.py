@@ -664,3 +664,44 @@ def test_device_reads_count_the_reads_that_block(n_iters):
     _log_fit(ds, n_iters, fuse_steps=n_iters)
     fused = ds.system.stats.delta(before)
     assert fused.device_reads == 1 and fused.host_syncs == 1
+
+
+@pytest.mark.parametrize("n_init,fuse_steps", [(1, 1), (3, 1), (2, 3)])
+def test_kmeans_trace_has_one_init_and_one_finish_per_restart(
+        tmp_path, n_init, fuse_steps):
+    import jax
+    root = os.path.join(os.path.dirname(__file__), os.pardir)
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench import trace as xtrace
+    from repro.api import make_estimator
+    X = np.random.default_rng(0).normal(size=(512, 4)).astype(np.float32)
+    ds = make_system("pim", n_cores=8).put(X)
+
+    def fit():
+        return make_estimator("kmeans", version="int16", system=ds.system,
+                              n_clusters=3, max_iter=4, tol=0.0,
+                              n_init=n_init, fuse_steps=fuse_steps).fit(ds)
+    fit()                                  # compiles outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("bench.window"):
+            fit()
+    finally:
+        jax.profiler.stop_trace()
+    tr = xtrace.load(str(tmp_path), "bench.window")
+    hs, he, hn = tr.host
+
+    def spans(name):
+        return sorted(zip(hs[hn == name].tolist(), he[hn == name].tolist()))
+    inits, finishes = spans("repro.init"), spans("repro.finish")
+    steps = spans("repro.step")
+    assert len(inits) == n_init and len(finishes) == n_init
+    # each inside one trainer step, the draw before its restart's passes
+    assert sum(len(x) for x in _inside(steps, inits + finishes)) \
+        == 2 * n_init
+    assert all(i[1] <= f[0] for i, f in zip(inits, finishes))
+    # the inertia read, then the labels' read where the restart is the
+    # best so far (always the first)
+    reads = [len(x) for x in _inside(finishes, spans("repro.read"))]
+    assert reads[0] == 2 and set(reads) <= {1, 2}

@@ -34,7 +34,7 @@ def test_kmeans_assign_vmem():
     bn, f, k = 1024, 64, 64                       # generous upper bounds
     working = DBL * bn * f * 2                    # int16 point block
     working += k * f * 2                          # pinned centroids
-    working += (k * f + k + bn) * 4               # int32 sums/counts/labels
+    working += (2 * k * f + k + bn) * 4           # int32 sum pair/counts/labels
     assert working < VMEM_BUDGET
 
 

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro.core import dtree, kmeans
 from repro.kernels import dispatch
+from repro.kernels.quant_matmul.kernel import BLOCK_N, to_feature_blocks
 from repro.systems import PimConfig, PimSystem
 from .common import row, time_call
 
@@ -37,6 +38,7 @@ def run():
                     f"interp_us={ts['pallas_interpret'] * 1e6:.0f}"))
 
     xq = jnp.asarray(rng.randint(-1024, 1024, (4096, 16)), jnp.int32)
+    xq = to_feature_blocks(xq[None], BLOCK_N)[0]   # the int32 view's layout
     wq = jnp.asarray(rng.randint(-1024, 1024, (16,)), jnp.int32)
     ts = {be: time_call(dispatch.launch, "fx_matvec", xq, wq, 10,
                         backend=be) for be in _BACKENDS}

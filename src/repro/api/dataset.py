@@ -17,12 +17,15 @@ boundary per iteration.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..core.fixed_point import to_fixed
+from ..kernels.quant_matmul.kernel import block_rows, to_feature_blocks
 # 12-bit symmetric range stored in int16 — keeps int32 distance and
 # coordinate-sum accumulations exact on TPU; single source of truth in
 # core/kmeans.py (see its docstring for the derivation)
@@ -84,6 +87,12 @@ class PimDataset:
         return view
 
     @property
+    def n_pc(self) -> int:
+        """Rows of each shard ``System.shard_rows`` makes, padding
+        included."""
+        return -(-self.n // self.system.n_shards)
+
+    @property
     def n_views(self) -> int:
         """Number of materialized (transferred) views — diagnostics."""
         return sum(1 for k in self._views if k[0] != "mask")
@@ -110,6 +119,15 @@ class PimDataset:
         ``version`` may be any LIN/LOG version name; it is collapsed to
         the data precision family, so HYB and BUI (same datatypes, paper
         §3.1) share one transfer, as do the LUT placement variants.
+
+        fp32 and hyb shards are row-major: ``(shards, n_pc, F)``,
+        ``(shards, n_pc)``.  The int32 family is stored in
+        ``fx_matvec``'s blocked feature-major layout, built once on the
+        device: ``Xs`` ``(shards, nb, F8, bn)`` with the rows of each
+        block on the lanes and ``F`` padded to whole sublanes, ``ys``
+        and an int32 ``mask`` of its own ``(shards, nb, bn)``; pad rows
+        and features are zero, and 0 in the mask.  Rows stay on axis 1
+        in every layout (DESIGN.md §3.2).
         """
         y = self._require_y("gd_view")
         data_ver = gd_data_version(version)
@@ -127,9 +145,11 @@ class PimDataset:
             def build():
                 Xq = np.asarray(to_fixed(self.X, frac_bits))
                 yq = np.asarray(to_fixed(y, frac_bits))
-                return (self.system.shard_rows(Xq),
-                        self.system.shard_rows(yq),
-                        self.mask(jnp.int32))
+                Xs = self.system.shard_rows(Xq)
+                return _feature_blocks(
+                    (Xs, self.system.shard_rows(yq),
+                     self.system.row_validity_mask(self.n)),
+                    block_rows(Xs.shape[1]))
         else:  # hyb: int8 inputs, fixed-point targets at frac_bits
             key = ("gd", "hyb", x8_frac, frac_bits)
 
@@ -205,6 +225,15 @@ class PimDataset:
                               host_q=Xq,
                               scale=np.float32(scale))
         return self._cached(("kmeans", "int16"), build)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _feature_blocks(shards, bn: int):
+    """Row-major ``(Xs, ys, mask)`` shards in ``fx_matvec``'s blocked
+    layout, relaid on the device; the mask as int32."""
+    Xs, ys, mask = shards
+    return (to_feature_blocks(Xs, bn), to_feature_blocks(ys, bn),
+            to_feature_blocks(mask.astype(jnp.int32), bn))
 
 
 def as_dataset(X, y, system) -> PimDataset:

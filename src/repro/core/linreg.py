@@ -101,13 +101,15 @@ def make_local_grad_int32(frac_bits: int, backend=None):
     be = dispatch.resolve_backend(backend)
 
     def _local(Xq, yq, mask, wq, bq):
-        # Q-format matvec through the kernel-dispatch layer (op
-        # ``fx_matvec``; bit-identical to fixed_point.fx_dot)
+        # the int32 view's blocked layout (api/dataset.py::gd_view):
+        # Xq (nb, F8, bn), yq and mask (nb, bn).  Q-format matvec through
+        # the kernel-dispatch layer (op ``fx_matvec``; bit-identical to
+        # fixed_point.fx_dot on the rows)
         dot = dispatch.launch("fx_matvec", Xq, wq, frac_bits,
-                              backend=be) + bq          # Q(f)
+                              backend=be) + bq          # Q(f) (nb, bn)
         err = (dot - yq) * mask                         # Q(f)
-        prod = err[:, None] * Xq.astype(jnp.int32)      # Q(2f)
-        gw = fx_sum(_shift_round(prod, frac_bits), 0)   # Q(f) pairs
+        prod = err[:, None, :] * Xq                     # Q(2f)
+        gw = fx_sum(_shift_round(prod, frac_bits), (0, 2))[:wq.shape[0]]
         return {"gw": gw, "gb": fx_sum(err)}
     return _local
 
@@ -121,6 +123,24 @@ def make_local_grad_hyb(x8_frac: int, w16_frac: int, out_frac: int):
         gw = fx_sum(_shift_round(prod, x8_frac), 0)      # Q(out_frac)
         return {"gw": gw, "gb": fx_sum(err)}
     return _local
+
+
+def row_window(shards: tuple, start, size: int) -> tuple:
+    """The ``size`` rows from row ``start`` (may be traced) of every
+    shard of a GD view.  Row-major shards are sliced on axis 1; of the
+    int32 view's blocked layout (a 4-D ``Xs``) come the blocks that hold
+    the window, with the rows outside it masked out — a masked row adds
+    exact zeros to the integer gradient, so both give the same sums."""
+    if shards[0].ndim < 4:
+        return tuple(jax.lax.dynamic_slice_in_dim(a, start, size, axis=1)
+                     for a in shards)
+    nb, bn = shards[1].shape[1:]
+    k = min(nb, -(-size // bn) + 1)         # blocks that hold any window
+    b0 = jnp.minimum(start // bn, nb - k)
+    rows = (b0 + jnp.arange(k))[:, None] * bn + jnp.arange(bn)
+    Xs, ys, mask = (jax.lax.dynamic_slice_in_dim(a, b0, k, axis=1)
+                    for a in shards)
+    return Xs, ys, mask * ((rows >= start) & (rows < start + size))
 
 
 # ---------------------------------------------------------------------------
@@ -254,7 +274,7 @@ def fit_steps(dataset, cfg: Optional[GdConfig] = None,
     else:
         local = _grad_kernel(pim, cfg)
 
-    n_pc = Xs.shape[1]
+    n_pc = dataset.n_pc
     minibatch = bool(cfg.minibatch and cfg.minibatch < n_pc)
     # per-shard minibatches: n_shards == n_cores on PIM, 1 on a host
     # target (one resident image draws one batch)
@@ -316,12 +336,8 @@ def fit_steps(dataset, cfg: Optional[GdConfig] = None,
             # offsets arrive as scan xs, pre-drawn per chunk below from
             # the SAME rng stream the serial loop consumes — the fused
             # trajectory is the serial one, bit for bit
-            mb = cfg.minibatch
-
             def select(shards, off):
-                return tuple(
-                    jax.lax.dynamic_slice_in_dim(a, off, mb, axis=1)
-                    for a in shards)
+                return row_window(shards, off, cfg.minibatch)
         program = pim.step_program(
             local, prepare, update,
             name=(f"lin.step/{grad_kernel_name(cfg)}"
@@ -370,8 +386,7 @@ def fit_steps(dataset, cfg: Optional[GdConfig] = None,
                 # SGD: every core samples the same per-core slice offset
                 # (keeps shards aligned; bank-resident data never moves)
                 start = int(rng.randint(0, n_pc - cfg.minibatch + 1))
-                sl = (slice(None), slice(start, start + cfg.minibatch))
-                args = (Xs[sl], ys[sl], mask[sl])
+                args = row_window((Xs, ys, mask), start, cfg.minibatch)
             else:
                 args = (Xs, ys, mask)
             partial = pim.map_reduce(local, args, (wq, bq))

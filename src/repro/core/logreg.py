@@ -104,12 +104,13 @@ def make_local_grad(cfg: LogRegConfig, lut: Optional[SigmoidLut],
         terms = cfg.taylor_terms
 
         def _local_int32_taylor(Xq, yq, mask, wq, bq):
+            # blocked int32 view: Xq (nb, F8, bn), yq and mask (nb, bn)
             z = dispatch.launch("fx_matvec", Xq, wq, f,
-                                backend=be) + bq          # Q(f)
+                                backend=be) + bq          # Q(f) (nb, bn)
             p = taylor_sigmoid_fixed(z, f, terms=terms)   # Q(f)
             err = (p - yq) * mask
-            prod = err[:, None] * Xq.astype(jnp.int32)
-            gw = fx_sum(_shift_round(prod, f), 0)
+            prod = err[:, None, :] * Xq
+            gw = fx_sum(_shift_round(prod, f), (0, 2))[:wq.shape[0]]
             return {"gw": gw, "gb": fx_sum(err)}
         return _local_int32_taylor
 
@@ -117,14 +118,15 @@ def make_local_grad(cfg: LogRegConfig, lut: Optional[SigmoidLut],
         assert lut is not None
 
         def _local_int32_lut(Xq, yq, mask, wq, bq):
+            # blocked int32 view: Xq (nb, F8, bn), yq and mask (nb, bn)
             z = dispatch.launch("fx_matvec", Xq, wq, f,
-                                backend=be) + bq          # Q(f)
+                                backend=be) + bq          # Q(f) (nb, bn)
             p15 = dispatch.launch("lut_sigmoid", z, lut,
                                   backend=lut_be)         # Q(value_frac)
             p = _shift_round(p15, lut.value_frac - f)     # -> Q(f)
             err = (p - yq) * mask
-            prod = err[:, None] * Xq.astype(jnp.int32)
-            gw = fx_sum(_shift_round(prod, f), 0)
+            prod = err[:, None, :] * Xq
+            gw = fx_sum(_shift_round(prod, f), (0, 2))[:wq.shape[0]]
             return {"gw": gw, "gb": fx_sum(err)}
         return _local_int32_lut
 

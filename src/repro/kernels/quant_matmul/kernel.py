@@ -70,42 +70,78 @@ def int_matmul(a_q: jnp.ndarray, b_q: jnp.ndarray, *, bm: int = 128,
     )(a_q, b_q)
 
 
+#: rows of one ``fx_matvec`` block: the lane axis of the blocked
+#: feature-major layout the int32 GD view is stored in
+BLOCK_N = 1024
+LANES = 128
+SUBLANES = 8
+
+
+def block_rows(n: int) -> int:
+    """Rows of a block for a shard of ``n`` rows: ``BLOCK_N``, or, for a
+    shard of fewer than ``2 * BLOCK_N`` rows, half of them rounded up to
+    whole lanes — two blocks where the shard has more than a lane's rows,
+    so the grid pipelines one block's fetch behind the other's compute
+    and half a shard's blocks are half its rows."""
+    return min(BLOCK_N, -(-max(n, 1) // (2 * LANES)) * LANES)
+
+
+def to_feature_blocks(a: jnp.ndarray, bn: int) -> jnp.ndarray:
+    """Rows of each shard into ``fx_matvec``'s layout: ``(C, n)`` ->
+    ``(C, nb, bn)`` and ``(C, n, F)`` -> ``(C, nb, F8, bn)``, ``nb =
+    ceil(n / bn)``, the rows of each block on the last (lane) axis.
+    Rows stay on axis 1.  Rows past ``n`` are zero, and so are the
+    features past ``F`` up to ``F8``, ``F`` rounded up to whole sublanes:
+    for an ``F`` that is no multiple of 8 the TPU's default layout of
+    the array moves the cores axis into the sublanes to save ``F``'s
+    padding, and every launch would copy the view back into the
+    ``(F, bn)`` tiles the kernel reads."""
+    c, n = a.shape[:2]
+    nb = -(-n // bn)
+    pad = [(0, 0), (0, nb * bn - n)]
+    if a.ndim == 3:
+        pad.append((0, -a.shape[2] % SUBLANES))
+    a = jnp.pad(a, pad)
+    return jnp.moveaxis(a.reshape(c, nb, bn, *a.shape[2:]), 2, -1)
+
+
 def _fx_matvec_kernel(xt_ref, w_ref, o_ref, *, frac_bits: int):
-    x = xt_ref[...].astype(jnp.int32)                # (F, bn) rows on lanes
-    w = w_ref[...].astype(jnp.int32)                 # (F, 1)
+    x = xt_ref[...].astype(jnp.int32)                # (F8, bn) rows on lanes
+    w = w_ref[...].astype(jnp.int32)                 # (F8, 1)
     prod = x * w                                     # Q(2f)
     if frac_bits:
         prod = (prod + (1 << (frac_bits - 1))) >> frac_bits
     o_ref[...] = jnp.sum(prod, axis=0, keepdims=True)  # (1, bn) Q(f)
 
 
-@functools.partial(jax.jit, static_argnames=("frac_bits", "block_n",
-                                             "interpret"))
-def fx_matvec(x_q: jnp.ndarray, w_q: jnp.ndarray, *, frac_bits: int,
-              block_n: int = 1024, interpret: bool = False) -> jnp.ndarray:
-    """Q-format row-dot: int32[N, F] x int32[F] -> int32[N], each product
-    shifted back to Q(frac_bits) with round-to-nearest BEFORE accumulation
-    (the paper's 32-bit DPU dot-product ordering; bit-identical to
-    ``fixed_point.fx_dot``).  VPU work: the rows are transposed onto the
-    lane axis so every block and the output are lane-dense (also under
-    ``vmap``, which adds a squeezed cores axis in front), the weight
-    vector stays pinned — the kernel-tier path of the LIN/LOG INT32
-    versions' matmul."""
-    n, f = x_q.shape
-    assert w_q.shape == (f,), (x_q.shape, w_q.shape)
-    bn = min(block_n, n)
-    assert n % bn == 0, (n, bn)
+@functools.partial(jax.jit, static_argnames=("frac_bits", "interpret"))
+def fx_matvec(x_blk: jnp.ndarray, w_q: jnp.ndarray, *, frac_bits: int,
+              interpret: bool = False) -> jnp.ndarray:
+    """Q-format row-dot over the blocked layout: int32[nb, F8, bn] x
+    int32[F] -> int32[nb, bn] (``F <= F8``, the features past ``F``
+    weighted zero), each product shifted back to Q(frac_bits) with
+    round-to-nearest BEFORE accumulation (the paper's 32-bit DPU
+    dot-product ordering; bit-identical to ``fixed_point.fx_dot`` on the
+    rows).  VPU work: each grid step reads one contiguous ``(F8, bn)``
+    block, rows on the lanes, and writes its ``bn`` dots lane-dense
+    (also under ``vmap``, which adds a squeezed cores axis in front);
+    the weight vector stays pinned.  The input is stored in this layout
+    (:func:`to_feature_blocks`), so nothing is padded or transposed per
+    call — the kernel-tier path of the LIN/LOG INT32 versions' matmul."""
+    nb, f, bn = x_blk.shape
+    assert w_q.ndim == 1 and w_q.shape[0] <= f, (x_blk.shape, w_q.shape)
+    w = jnp.pad(w_q, (0, f - w_q.shape[0]))  # zero weights on pad features
     out = pallas_call(
         functools.partial(_fx_matvec_kernel, frac_bits=frac_bits),
         name="fx_matvec",
-        grid=(n // bn,),
+        grid=(nb,),
         in_specs=[
-            pl.BlockSpec((f, bn), lambda i: (0, i)),
+            pl.BlockSpec((None, f, bn), lambda i: (i, 0, 0)),
             pl.BlockSpec((f, 1), lambda i: (0, 0)),  # weights pinned
         ],
-        out_specs=pl.BlockSpec((1, bn), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, n), jnp.int32),
+        out_specs=pl.BlockSpec((None, 1, bn), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb, 1, bn), jnp.int32),
         dimension_semantics=("parallel",),
         interpret=interpret,
-    )(x_q.T, w_q.reshape(f, 1))
-    return out[0]
+    )(x_blk, w.reshape(f, 1))
+    return out.reshape(nb, bn)

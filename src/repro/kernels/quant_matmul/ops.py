@@ -4,8 +4,10 @@ Ops (registered with :mod:`repro.kernels.dispatch`):
 
 ``quant_matmul`` : dequantizing int8 matmul (kernel or XLA ref path)
 ``int_matmul``   : raw int8 x int8 -> int32 accumulator
-``fx_matvec``    : Q-format row-dot with pre-accumulation rounding —
-                   the kernel-tier path of the LIN/LOG INT32 versions'
+``fx_matvec``    : Q-format row-dot with pre-accumulation rounding over
+                   the blocked feature-major layout the int32 GD view
+                   is stored in (``to_feature_blocks``) — the
+                   kernel-tier path of the LIN/LOG INT32 versions'
                    matmul (bit-identical to ``fixed_point.fx_dot``)
 ``quant_dense``  : float-in/float-out PIM-style dense layer — quantizes
                    activations on the fly (per-tensor) against int8
@@ -28,7 +30,7 @@ from repro.core.quantization import symmetric_quantize
 from ..dispatch import legacy_launch, register_op
 from .kernel import fx_matvec as _fx_matvec_kernel
 from .kernel import int_matmul
-from .ref import int_matmul_ref, quant_matmul_ref
+from .ref import fx_matvec_ref, int_matmul_ref, quant_matmul_ref
 
 
 def quant_matmul(a_q, b_q, a_scale, b_scale, *, backend=None,
@@ -42,32 +44,18 @@ def quant_matmul(a_q, b_q, a_scale, b_scale, *, backend=None,
                          interpret=interpret, out_dtype=out_dtype)
 
 
-def fx_matvec(x_q, w_q, frac_bits: int, *, backend=None,
-              use_pallas: bool = None, interpret: bool = None,
-              block_n: int = 1024):
-    """Q(f)[N, F] . Q(f)[F] -> Q(f)[N] with per-product rounding."""
-    return legacy_launch("fx_matvec", x_q, w_q, frac_bits,
+def fx_matvec(x_blk, w_q, frac_bits: int, *, backend=None,
+              use_pallas: bool = None, interpret: bool = None):
+    """Q(f)[nb, F8, bn] . Q(f)[F] -> Q(f)[nb, bn] with per-product
+    rounding, over the blocked layout of ``kernel.to_feature_blocks``."""
+    return legacy_launch("fx_matvec", x_blk, w_q, frac_bits,
                          backend=backend, use_pallas=use_pallas,
-                         interpret=interpret, block_n=block_n)
+                         interpret=interpret)
 
 
-def _fx_matvec_ref(x_q, w_q, frac_bits: int, *, block_n: int = 1024):
-    from repro.core.fixed_point import fx_dot
-    del block_n  # jnp oracle needs no tiling
-    return fx_dot(x_q, w_q, frac_bits)
-
-
-def _fx_matvec_pallas(x_q, w_q, frac_bits: int, *, interpret: bool = True,
-                      block_n: int = 1024):
-    n = x_q.shape[0]
-    bn = min(block_n, max(n, 8))
-    n_pad = -(-n // bn) * bn
-    if n_pad != n:  # ragged tail: zero rows dot to zero, slice them off
-        x_q = jnp.zeros((n_pad, x_q.shape[1]),
-                        x_q.dtype).at[:n].set(x_q)
-    out = _fx_matvec_kernel(x_q, w_q, frac_bits=frac_bits, block_n=bn,
-                            interpret=interpret)
-    return out[:n]
+def _fx_matvec_pallas(x_blk, w_q, frac_bits: int, *, interpret: bool = True):
+    return _fx_matvec_kernel(x_blk, w_q, frac_bits=frac_bits,
+                             interpret=interpret)
 
 
 def _int_matmul_ref_op(a_q, b_q, *, bm=128, bn=128, bk=128):
@@ -111,4 +99,4 @@ register_op("int_matmul", family="quant_matmul",
 register_op("quant_matmul", family="quant_matmul",
             pallas=_quant_matmul_pallas, ref=quant_matmul_ref)
 register_op("fx_matvec", family="quant_matmul",
-            pallas=_fx_matvec_pallas, ref=_fx_matvec_ref)
+            pallas=_fx_matvec_pallas, ref=fx_matvec_ref)

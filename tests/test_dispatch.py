@@ -112,17 +112,26 @@ def test_int_matmul_backend_parity():
     np.testing.assert_array_equal(np.asarray(o1), np.asarray(o2))
 
 
+def _blocked(xq, bn):
+    """One shard's rows in fx_matvec's blocked layout."""
+    from repro.kernels.quant_matmul.kernel import to_feature_blocks
+    return to_feature_blocks(xq[None], bn)[0]
+
+
 @pytest.mark.parametrize("n,f", [(64, 16), (100, 7)])   # incl. ragged tail
 def test_fx_matvec_backend_parity_and_oracle(n, f):
     rng = np.random.RandomState(n)
     xq = jnp.asarray(rng.randint(-1024, 1024, (n, f)), jnp.int32)
     wq = jnp.asarray(rng.randint(-1024, 1024, (f,)), jnp.int32)
-    outs = [dispatch.launch("fx_matvec", xq, wq, 10, backend=be,
-                            block_n=32) for be in BACKENDS]
+    xb = _blocked(xq, 32)
+    assert xb.shape == (-(-n // 32), -(-f // 8) * 8, 32)
+    outs = [dispatch.launch("fx_matvec", xb, wq, 10, backend=be)
+            for be in BACKENDS]
     np.testing.assert_array_equal(np.asarray(outs[0]), np.asarray(outs[1]))
-    # the ref IS fixed_point.fx_dot — the trainers' pre-dispatch hot path
-    np.testing.assert_array_equal(np.asarray(outs[0]),
-                                  np.asarray(fx_dot(xq, wq, 10)))
+    # row for row, the ref IS fixed_point.fx_dot; the pad rows dot to 0
+    flat = np.asarray(outs[0]).reshape(-1)
+    np.testing.assert_array_equal(flat[:n], np.asarray(fx_dot(xq, wq, 10)))
+    assert not flat[n:].any()
 
 
 def test_mha_backend_parity_tolerance():
@@ -135,14 +144,16 @@ def test_mha_backend_parity_tolerance():
 
 @pytest.mark.parametrize("use_pallas", [False, True])
 def test_fx_matvec_public_wrapper_ragged(use_pallas):
-    """The public ops wrapper must pad ragged N like the dispatch path
-    (it once called the raw kernel and tripped its block assert)."""
+    """The public ops wrapper takes the blocked layout of a ragged N
+    like the dispatch path (it once called the raw kernel and tripped
+    its block assert)."""
     from repro.kernels.quant_matmul.ops import fx_matvec
     rng = np.random.RandomState(0)
     xq = jnp.asarray(rng.randint(-512, 512, (100, 5)), jnp.int32)
     wq = jnp.asarray(rng.randint(-512, 512, (5,)), jnp.int32)
-    out = fx_matvec(xq, wq, 10, use_pallas=use_pallas, block_n=64)
-    np.testing.assert_array_equal(np.asarray(out),
+    out = fx_matvec(_blocked(xq, 64), wq, 10, use_pallas=use_pallas)
+    assert out.shape == (2, 64)
+    np.testing.assert_array_equal(np.asarray(out).reshape(-1)[:100],
                                   np.asarray(fx_dot(xq, wq, 10)))
 
 

@@ -14,12 +14,38 @@ from repro.core.lut import SigmoidLut, build_sigmoid_lut
 # ---------------------------------------------------------------------------
 # quant_matmul
 # ---------------------------------------------------------------------------
-from repro.kernels.quant_matmul.kernel import int_matmul
+from repro.kernels.quant_matmul.kernel import (fx_matvec, int_matmul,
+                                               to_feature_blocks)
 from repro.kernels.quant_matmul.ops import quant_dense, quant_matmul
 from repro.kernels.quant_matmul.ref import int_matmul_ref, quant_matmul_ref
 
 
 slow = pytest.mark.slow  # large-shape interpret-mode cases (tier-1 only)
+
+
+@pytest.mark.parametrize("n,f,bn", [
+    (1024, 8, 1024),                  # one whole block, no feature pad
+    (2500, 18, 1024),                 # ragged rows, 18 -> 24 features
+    (300, 5, 128),                    # rows past several small blocks
+])
+def test_fx_matvec_blocked_kernel_matches_fx_dot(n, f, bn):
+    """The interpret-mode kernel over the blocked layout equals
+    ``fixed_point.fx_dot`` row for row, with rounding ties of both signs
+    and the Q10 range's extremes; pad rows and features add nothing."""
+    from repro.core.fixed_point import fx_dot
+    rng = np.random.RandomState(n + f)
+    x = rng.randint(-2 ** 20, 2 ** 20, (n, f))
+    x[:4] = [[2 ** 20 - 1], [-2 ** 20], [512], [-512]]   # extremes, ties
+    w = rng.randint(-1024, 1024, f)
+    w[0] = 1
+    xq, wq = jnp.asarray(x, jnp.int32), jnp.asarray(w, jnp.int32)
+    xb = to_feature_blocks(xq[None], bn)[0]
+    assert xb.shape == (-(-n // bn), -(-f // 8) * 8, bn)
+    out = fx_matvec(xb, wq, frac_bits=10, interpret=True)
+    assert out.shape == (xb.shape[0], bn) and out.dtype == jnp.int32
+    flat = np.asarray(out).reshape(-1)
+    np.testing.assert_array_equal(flat[:n], np.asarray(fx_dot(xq, wq, 10)))
+    assert not flat[n:].any()
 
 
 @pytest.mark.parametrize("m,k,n,bm,bk,bn", [

@@ -11,6 +11,8 @@ The topology is described inside the ``topo`` fixture, never at import:
 only one process may load the TPU library at a time, and every test
 worker imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -22,6 +24,9 @@ from repro.kernels import dispatch
 CORES = 64                      # default PimConfig
 SUSY_PC = 5_000_000 // CORES    # 78,125 rows per core
 HIGGS_PC = -(-11_000_000 // CORES)   # 171,875 rows per core
+#: the int32 GD view of SUSY: 77 blocks of 1,024 rows, 18 features
+#: padded to 24 (api/dataset.py::gd_view)
+SUSY_VIEW = (CORES, -(-SUSY_PC // 1024), 24, 1024)
 EMB_ROWS = 1_048_576 // CORES   # 16,384 rows x 128 x 4 B = 8 MiB shard
 EMB_DIM, EMB_BATCH = 128, 4096
 TPU = dispatch.KernelBackend.PALLAS_TPU
@@ -68,8 +73,31 @@ def test_fx_matvec_vmapped_susy(one_chip):
     run = _per_core(lambda x, w: dispatch.launch(
         "fx_matvec", x, w, 10, backend=TPU))
     hlo = _compile(lambda x, w: run((x,), (w,)), one_chip,
-                   ((CORES, SUSY_PC, 18), jnp.int32), ((18,), jnp.int32))
+                   (SUSY_VIEW, jnp.int32), ((18,), jnp.int32))
     assert "tpu_custom_call" in hlo
+
+
+def test_log_int32_step_reads_the_view_in_place(one_chip):
+    """One LOG int32_lut_wram step over the SUSY view, as ``map_reduce``
+    compiles it, with the arrays in the chip's default layouts: the
+    resident rows feed ``fx_matvec`` and the gradient reduce as they
+    are stored — no copy, pad or transpose writes them again.  With 18
+    features unpadded, the default layout moves the cores axis into
+    the sublanes and a copy of the view appears here."""
+    from repro.core import logreg
+    local = logreg.build_local_grad(logreg.LogRegConfig(
+        version="int32_lut_wram", kernel_backend=TPU.value))
+    run = _per_core(local)
+    rows = SUSY_VIEW[:2] + SUSY_VIEW[3:]
+    hlo = _compile(lambda x, y, m, w, b: run((x, y, m), (w, b)), one_chip,
+                   (SUSY_VIEW, jnp.int32), (rows, jnp.int32),
+                   (rows, jnp.int32), ((18,), jnp.int32), ((), jnp.int32))
+    assert "tpu_custom_call" in hlo
+    view = "s32[" + ",".join(map(str, SUSY_VIEW)) + "]"
+    rewrites = [ln for ln in hlo.splitlines()
+                if re.search(r"= " + re.escape(view)
+                             + r"\S* (copy|pad|transpose|fusion)\(", ln)]
+    assert not rewrites, rewrites[:3]
 
 
 def test_int_matmul(one_chip):

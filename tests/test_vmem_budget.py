@@ -21,6 +21,27 @@ def test_quant_matmul_vmem():
     assert working < 512 * 2 ** 10                # actually tiny: < 512 KiB
 
 
+def test_fx_matvec_vmem():
+    """One contiguous (F8, bn) int32 block of the blocked int32 view a
+    grid step, double-buffered, the pinned weights and the (1, bn)
+    output block (a sublane tile each in VMEM)."""
+    from repro.kernels.quant_matmul.kernel import (BLOCK_N, SUBLANES,
+                                                   block_rows)
+    bn = BLOCK_N
+    assert block_rows(78_125) == bn           # SUSY per core: 77 blocks
+    assert block_rows(1_024) == 512           # small shards: two blocks
+    assert block_rows(300) == 256             # of whole lanes
+    assert block_rows(100) == 128
+    f8 = 24                                   # SUSY's 18 features
+    working = DBL * f8 * bn * 4               # streamed int32 x block
+    working += DBL * f8 * 128 * 4             # pinned (F8, 1) weights
+    working += DBL * SUBLANES * bn * 4        # (1, bn) int32 out block
+    assert working < 512 * 2 ** 10            # 320 KiB
+    wide = DBL * 128 * bn * 4 + DBL * 128 * 128 * 4 \
+        + DBL * SUBLANES * bn * 4             # 128 features still fit
+    assert wide < VMEM_BUDGET
+
+
 def test_flash_attention_vmem():
     bq = bk = 128
     d = 256                                       # generous head dim

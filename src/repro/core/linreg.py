@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import dataclasses
 import warnings
-from typing import Callable, Optional
+from typing import Callable, Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -58,7 +58,8 @@ class GdConfig:
     #: step fusion (DESIGN.md §9): compile this many consecutive GD
     #: iterations into ONE lax.scan launch — the whole kernel -> reduce
     #: -> update -> re-quantize cycle stays on device between chunk
-    #: boundaries.  1 = the host-orchestrated per-step loop.  Works for
+    #: boundaries.  1 = one host-orchestrated step per chunk (the
+    #: one-step cadence of ``StepProgram.run``).  Works for
     #: minibatch SGD too (DESIGN.md §9.5): the host pre-draws each
     #: chunk's batch offsets from the same rng stream the serial loop
     #: uses and feeds them through the scan as per-step inputs, so the
@@ -72,7 +73,8 @@ class GdConfig:
     #: snapshot).  2 = double-buffered — chunk N+1 executes while the
     #: host processes boundary N; 1 = the serial dispatch-drain cadence
     #: (with carry donation).  Bit-identical either way: pipelining
-    #: reorders host work only.  Ignored unless ``fuse_steps > 1``.
+    #: reorders host work only.  Applies to chunks of more than one
+    #: step; at ``fuse_steps=1`` every step drains before the next.
     pipeline_depth: int = 2
 
 
@@ -144,7 +146,7 @@ def row_window(shards: tuple, start, size: int) -> tuple:
 
 
 # ---------------------------------------------------------------------------
-# Host-orchestrated training loop (paper §3.1 flow).
+# The gradient-descent driver shared by LIN and LOG (paper §3.1 flow).
 # ---------------------------------------------------------------------------
 
 def _quantize_weights(cfg: GdConfig, w: np.ndarray, b: float):
@@ -162,10 +164,11 @@ def make_gd_step_fns(quant_cfg: GdConfig):
     ``prepare(carry) -> (wq, bq)`` quantizes the float32 carry for the
     broadcast; ``update(carry, reduced) -> (carry, None)`` dequantizes
     the reduced gradient and applies ``w -= scale_f32 * gw`` — all jnp
-    ops, so ONE definition serves the host-orchestrated per-step loop,
-    the fused :class:`~repro.core.pim.StepProgram` scan, and (batched
-    over a lane axis) the scheduler's fused gangs; the paths cannot
-    drift numerically.  ``quant_cfg`` is the weight-quantization config
+    ops, so ONE definition serves both paths of
+    :meth:`~repro.systems.base.StepProgram.run` (the per-step path and
+    the scan), and the scheduler's fused gangs apply the same
+    elementwise math over a lane axis; the paths cannot drift
+    numerically.  ``quant_cfg`` is the weight-quantization config
     (LOG's LUT versions pass their collapsed int32/hyb base).
 
     Gradients stay on device: the old loop's per-step
@@ -239,51 +242,72 @@ def grad_kernel_name(cfg: GdConfig) -> str:
     return f"lin.grad/hyb/x{cfg.x8_frac}.w{cfg.w16_frac}.f{cfg.frac_bits}"
 
 
-def _grad_kernel(pim: System, cfg: GdConfig):
-    """Named per-core gradient kernel for the configured version
-    (registered once per System; reused across fits and sweeps)."""
-    return pim.named_kernel(grad_kernel_name(cfg),
-                            lambda: build_local_grad(cfg))
+@dataclasses.dataclass(frozen=True)
+class GdFamily:
+    """What one gradient-descent workload gives the shared driver
+    (:func:`fit_steps`) and the scheduler's fused gangs (DESIGN.md
+    §7.3); state, chunks, minibatch offsets and snapshots are common.
+    A workload opts into both by writing one record."""
+
+    #: jit-cache namespace of the step programs (``lin`` -> ``lin.step/``)
+    name: str
+    #: ``(system, cfg) -> (registry name, builder)`` of the per-core
+    #: kernel; the name encodes every parameter the builder bakes in,
+    #: including what the system decides (LOG's exact sigmoid)
+    kernel: Callable[[System, GdConfig], Tuple[str, Callable[[], Callable]]]
+    #: the update is ``w -= lr * grad_scale / n * gw`` (MSE: 2, logistic: 1)
+    grad_scale: float
+    #: data version -> the version whose weight quantization it uses
+    base_version: Mapping[str, str]
+
+
+LIN = GdFamily(
+    name="lin",
+    kernel=lambda system, cfg: (grad_kernel_name(cfg),
+                                lambda: build_local_grad(cfg)),
+    grad_scale=2.0,
+    base_version={v: v for v in VERSIONS})
 
 
 def fit_steps(dataset, cfg: Optional[GdConfig] = None,
-              eval_fn: Optional[Callable] = None,
-              _local_override: Optional[Callable] = None, *,
-              state: Optional[dict] = None):
-    """Generator form of the training loop; the GdResult travels on
-    StopIteration.  This is the gang-stepping surface the job scheduler
-    interleaves (DESIGN.md §7.3); :func:`fit` drains it.
+              eval_fn: Optional[Callable] = None, *,
+              state: Optional[dict] = None, family: GdFamily = LIN):
+    """The gradient-descent driver, in generator form; the GdResult
+    travels on StopIteration.  ``family`` says which workload it trains
+    (LIN here, ``logreg.LOG`` for LOG).  This is the gang-stepping
+    surface the job scheduler interleaves (DESIGN.md §7.3); :func:`fit`
+    drains it.
 
-    Each ``next()`` advances one *scheduling step* and yields a
+    Each ``next()`` advances one chunk of ``chunk_schedule`` through a
+    :class:`~repro.systems.base.StepProgram` and yields a
     :class:`~repro.systems.base.ChunkTick` — the number of GD iterations
-    it covered (1 per host-orchestrated step, up to ``cfg.fuse_steps``
-    per fused :class:`~repro.core.pim.StepProgram` chunk — DESIGN.md
-    §9) carrying a lazy chunk-boundary snapshot of the carry.  Passing
-    such a snapshot back as ``state`` resumes the fit exactly where it
-    was preempted: the carry, the history, and the full minibatch rng
-    stream restore, so a resumed integer fit is bit-identical to an
-    uninterrupted one (DESIGN.md §11.2)."""
+    it covered (up to ``cfg.fuse_steps`` — DESIGN.md §9) carrying a lazy
+    chunk-boundary snapshot of the carry.  Passing such a snapshot back
+    as ``state`` resumes the fit exactly where it was preempted: the
+    carry, the history, and the full minibatch rng stream restore, so a
+    resumed integer fit is bit-identical to an uninterrupted one
+    (DESIGN.md §11.2)."""
     cfg = cfg or GdConfig()
-    assert cfg.version in VERSIONS, cfg.version
+    assert cfg.version in family.base_version, cfg.version
     pim = dataset.system
     n, f = dataset.n, dataset.n_features
     Xs, ys, mask = dataset.gd_view(cfg.version, cfg.frac_bits, cfg.x8_frac)
-
-    if _local_override is not None:
-        local = _local_override
-    else:
-        local = _grad_kernel(pim, cfg)
+    kname, build = family.kernel(pim, cfg)
+    local = pim.named_kernel(kname, build)
 
     n_pc = dataset.n_pc
     minibatch = bool(cfg.minibatch and cfg.minibatch < n_pc)
     # per-shard minibatches: n_shards == n_cores on PIM, 1 on a host
     # target (one resident image draws one batch)
     n_eff = cfg.minibatch * pim.n_shards if minibatch else n
-    prepare, update = make_gd_step_fns(cfg)
+    # weights quantize at the collapsed data precision (LOG's LUT
+    # versions like their int32/hyb base)
+    prepare, update = make_gd_step_fns(dataclasses.replace(
+        cfg, version=family.base_version[cfg.version]))
 
     w = jnp.zeros(f, jnp.float32)
     b = jnp.float32(0.0)
-    s = jnp.float32(cfg.lr * (2.0 / n_eff))
+    s = jnp.float32(cfg.lr * (family.grad_scale / n_eff))
     history = []
     rng = np.random.RandomState(cfg.seed)
     it_done = 0
@@ -324,95 +348,73 @@ def fit_steps(dataset, cfg: Optional[GdConfig] = None,
             return {"arrays": arrays, "meta": meta}
         return _snap
 
-    def _snapshot():
-        ra, rm = pack_rng(rng)
-        return _make_snapshot(w, b, s, it_done, ra, rm)()
+    select = None
+    if minibatch:
+        # minibatch SGD (DESIGN.md §9.5): the select hook slices every
+        # shard to the step's batch window; the offsets arrive as
+        # per-step inputs, drawn per chunk below from ONE rng stream —
+        # every chunk size walks the same trajectory, bit for bit
+        def select(shards, off):
+            return row_window(shards, off, cfg.minibatch)
+    program = pim.step_program(
+        local, prepare, update,
+        name=(f"{family.name}.step/{kname}/lr{cfg.lr}/n{n_eff}"
+              + (f"/mb{cfg.minibatch}" if minibatch else "")),
+        select=select)
+    # Chunk pipeline (DESIGN.md §14.1): dispatch chunk N+1, then drain
+    # boundary N — record/eval and the snapshot closure read the
+    # boundary's own carry while the next chunk executes.  The only host
+    # reads are on drained boundaries.  The one-step cadence drains each
+    # step before the next (depth 1), as ``pipeline_depth`` documents.
+    fuse = max(1, int(cfg.fuse_steps))
+    pipe = ChunkPipeline(program,
+                         max(1, int(cfg.pipeline_depth)) if fuse > 1 else 1)
 
-    if cfg.fuse_steps > 1:
-        select = None
+    def _drain(bnd):
+        nonlocal it_done
+        it_done, ra, rm = bnd.tag
+        bw, bb, bs = bnd.carry
+        record(it_done, bw, bb)
+        return ChunkTick(bnd.k, _make_snapshot(bw, bb, bs, it_done, ra, rm))
+
+    # resume replays identical chunk boundaries: chunk_schedule is a
+    # deterministic function of the iteration index (DESIGN.md §11.2)
+    it_disp = it_done
+    rng_pack = pack_rng(rng)        # full-batch GD never draws
+    for k in chunk_schedule(cfg.n_iters, fuse, cfg.record_every,
+                            start=it_done):
+        xs = None
         if minibatch:
-            # minibatch SGD fuses too (DESIGN.md §9.5): the select hook
-            # slices every shard to the step's batch window; the
-            # offsets arrive as scan xs, pre-drawn per chunk below from
-            # the SAME rng stream the serial loop consumes — the fused
-            # trajectory is the serial one, bit for bit
-            def select(shards, off):
-                return row_window(shards, off, cfg.minibatch)
-        program = pim.step_program(
-            local, prepare, update,
-            name=(f"lin.step/{grad_kernel_name(cfg)}"
-                  f"/lr{cfg.lr}/n{n_eff}"
-                  + (f"/mb{cfg.minibatch}" if minibatch else "")),
-            select=select)
-        # Double-buffered chunk pipeline (DESIGN.md §14.1): dispatch
-        # chunk N+1, then drain boundary N — record/eval and the
-        # snapshot closure read the boundary's own carry while the next
-        # chunk executes.  The only host reads are on drained
-        # boundaries, so the device never waits on record work.
-        pipe = ChunkPipeline(program, max(1, int(cfg.pipeline_depth)))
-
-        def _drain(bnd):
-            nonlocal it_done
-            it_done, ra, rm = bnd.tag
-            bw, bb, bs = bnd.carry
-            record(it_done, bw, bb)
-            return ChunkTick(bnd.k, _make_snapshot(bw, bb, bs, it_done,
-                                                   ra, rm))
-
-        # resume replays identical chunk boundaries: chunk_schedule is a
-        # deterministic function of the iteration index (DESIGN.md §11.2)
-        it_disp = it_done
-        for k in chunk_schedule(cfg.n_iters, cfg.fuse_steps,
-                                cfg.record_every, start=it_done):
-            xs = None
-            if minibatch:
-                xs = jnp.asarray(
-                    [rng.randint(0, n_pc - cfg.minibatch + 1)
-                     for _ in range(k)], jnp.int32)
-            it_disp += k
+            xs = jnp.asarray(
+                [rng.randint(0, n_pc - cfg.minibatch + 1)
+                 for _ in range(k)], jnp.int32)
             # rng packed AFTER this chunk's draws: restoring boundary N
             # replays chunk N+1's batch offsets bit-exactly
-            (w, b, s), drained = pipe.dispatch(
-                (w, b, s), (Xs, ys, mask), k, xs=xs,
-                tag=(it_disp, *pack_rng(rng)))
-            for bnd in drained:
-                yield _drain(bnd)
-        for bnd in pipe.flush():
+            rng_pack = pack_rng(rng)
+        it_disp += k
+        (w, b, s), drained = pipe.dispatch(
+            (w, b, s), (Xs, ys, mask), k, xs=xs, tag=(it_disp, *rng_pack))
+        for bnd in drained:
             yield _drain(bnd)
-    else:
-        for it in range(it_done, cfg.n_iters):
-            wq, bq = pim.broadcast(prepare((w, b, s)))
-            if minibatch:
-                # SGD: every core samples the same per-core slice offset
-                # (keeps shards aligned; bank-resident data never moves)
-                start = int(rng.randint(0, n_pc - cfg.minibatch + 1))
-                args = row_window((Xs, ys, mask), start, cfg.minibatch)
-            else:
-                args = (Xs, ys, mask)
-            partial = pim.map_reduce(local, args, (wq, bq))
-            (w, b, s), _ = update((w, b, s), partial)
-            it_done = it + 1
-            record(it_done, w, b)
-            yield ChunkTick(1, _snapshot)
+    for bnd in pipe.flush():
+        yield _drain(bnd)
     w, b = pim.read((w, b))
     return GdResult(w=np.asarray(w, np.float32), b=float(b),
                     history=history, n_iters=cfg.n_iters)
 
 
 def fit(dataset, cfg: Optional[GdConfig] = None,
-        eval_fn: Optional[Callable] = None,
-        _local_override: Optional[Callable] = None) -> GdResult:
+        eval_fn: Optional[Callable] = None) -> GdResult:
     """Full PIM training loop over a bank-resident PimDataset: iterate
     (kernel -> reduce -> host update -> broadcast) until cfg.n_iters.
     The dataset's quantized view is materialized at most once per
     (version, Q-format) — repeated fits reuse the resident shards."""
-    return run_steps(fit_steps(dataset, cfg, eval_fn, _local_override))
+    return run_steps(fit_steps(dataset, cfg, eval_fn))
 
 
 def train(X: np.ndarray, y: np.ndarray, pim: System,
           cfg: Optional[GdConfig] = None,
-          eval_fn: Optional[Callable] = None,
-          _local_override: Optional[Callable] = None) -> GdResult:
+          eval_fn: Optional[Callable] = None) -> GdResult:
     """Deprecated shim: re-partitions (X, y) on every call.  Prefer
     ``fit(pim.put(X, y), cfg)`` which keeps the shards bank-resident
     across fits (repro.api)."""
@@ -420,7 +422,7 @@ def train(X: np.ndarray, y: np.ndarray, pim: System,
                   "linreg.fit(pim.put(X, y), cfg)", DeprecationWarning,
                   stacklevel=2)
     from ..api.dataset import as_dataset
-    return fit(as_dataset(X, y, pim), cfg, eval_fn, _local_override)
+    return fit(as_dataset(X, y, pim), cfg, eval_fn)
 
 # The CPU comparison point (paper §5.4) is no longer an ad-hoc numpy
 # loop here: run this same workload on repro.systems.HostSystem — the

@@ -25,10 +25,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels import dispatch
-from ..systems import (ChunkPipeline, ChunkTick, System, chunk_schedule,
-                       run_steps)
+from ..systems import System, run_steps
+from . import linreg
 from .fixed_point import _shift_round, fx_dot_hybrid, fx_sum
-from .linreg import GdConfig, GdResult, make_gd_step_fns
+from .linreg import GdConfig, GdResult
 from .lut import SigmoidLut, build_sigmoid_lut, taylor_sigmoid_fixed
 
 VERSIONS = ("fp32", "int32", "int32_lut_mram", "int32_lut_wram",
@@ -58,12 +58,6 @@ def _sigmoid_taylor_f32(z: jnp.ndarray, terms: int) -> jnp.ndarray:
     e = acc ** 8  # (exp(-t))**8 = exp(-a)
     pos = 1.0 / (1.0 + e)
     return jnp.where(z < 0, 1.0 - pos, pos)
-
-
-def _gd_version_of(version: str) -> str:
-    return {"fp32": "fp32", "int32": "int32", "int32_lut_mram": "int32",
-            "int32_lut_wram": "int32", "hyb_lut": "hyb",
-            "bui_lut": "bui"}[version]
 
 
 def make_local_grad(cfg: LogRegConfig, lut: Optional[SigmoidLut],
@@ -150,18 +144,11 @@ def make_local_grad(cfg: LogRegConfig, lut: Optional[SigmoidLut],
 def build_local_grad(cfg: LogRegConfig,
                      exact_sigmoid: bool = False) -> Callable:
     """Per-core kernel for ``cfg.version`` with its LUT built in
-    (unregistered) — shared by the serial trainer and the scheduler's
-    fused gang step (DESIGN.md §7.3)."""
+    (unregistered) — shared by the GD driver and the scheduler's fused
+    gang step through :data:`LOG` (DESIGN.md §7.3)."""
     lut = (build_sigmoid_lut(cfg.lut_boundary, cfg.lut_frac_bits)
            if "lut" in cfg.version else None)
     return make_local_grad(cfg, lut, exact_sigmoid)
-
-
-def _exact_sigmoid(system: System, cfg: LogRegConfig) -> bool:
-    """fp32 on a processor-centric target uses the exact sigmoid (the
-    paper's MKL/cuML baselines); every other combination keeps the
-    paper's DPU Taylor expansion."""
-    return cfg.version == "fp32" and system.exact_transcendentals
 
 
 def grad_kernel_name(cfg: LogRegConfig, exact_sigmoid: bool = False) -> str:
@@ -177,112 +164,33 @@ def grad_kernel_name(cfg: LogRegConfig, exact_sigmoid: bool = False) -> str:
             f"/{dispatch.backend_tag(cfg.kernel_backend)}")
 
 
-def _grad_kernel(pim: System, cfg: LogRegConfig) -> str:
-    """Named per-core kernel.  The sigmoid LUT is built inside the
-    builder — pay-once like the kernel, not per fit."""
-    exact = _exact_sigmoid(pim, cfg)
-    return pim.named_kernel(grad_kernel_name(cfg, exact),
-                            lambda: build_local_grad(cfg, exact))
+def _kernel(system: System, cfg: LogRegConfig):
+    """LOG's per-core kernel on ``system``: fp32 on a processor-centric
+    target uses the exact sigmoid (the paper's MKL/cuML baselines);
+    every other combination keeps the paper's DPU Taylor expansion.
+    The sigmoid LUT is built inside the builder — pay-once like the
+    kernel, not per fit."""
+    exact = cfg.version == "fp32" and system.exact_transcendentals
+    return (grad_kernel_name(cfg, exact),
+            lambda: build_local_grad(cfg, exact))
+
+
+#: LOG in the shared GD driver: the logistic gradient's 1/n scale, and
+#: LUT versions quantize their weights like their int32/hyb base
+LOG = linreg.GdFamily(
+    name="log", kernel=_kernel, grad_scale=1.0,
+    base_version={"fp32": "fp32", "int32": "int32",
+                  "int32_lut_mram": "int32", "int32_lut_wram": "int32",
+                  "hyb_lut": "hyb", "bui_lut": "bui"})
 
 
 def fit_steps(dataset, cfg: Optional[LogRegConfig] = None,
               eval_fn: Optional[Callable] = None, *,
               state: Optional[dict] = None):
-    """Generator form of the LOG loop (GdResult on StopIteration) — the
-    gang-stepping surface; :func:`fit` drains it.  Each ``next()``
-    yields a :class:`~repro.systems.base.ChunkTick`: the number of GD
-    iterations it advanced (1 per host-orchestrated step, up to
-    ``cfg.fuse_steps`` per fused :class:`~repro.core.pim.StepProgram`
-    chunk — DESIGN.md §9) with a lazy carry snapshot; pass a snapshot
-    back as ``state`` to resume bit-exactly at that chunk boundary
-    (DESIGN.md §11.2)."""
-    cfg = cfg or LogRegConfig()
-    assert cfg.version in VERSIONS, cfg.version
-    pim = dataset.system
-    n, nf = dataset.n, dataset.n_features
-
-    # reuse linreg's weight quantization via the base data version
-    base_cfg = dataclasses.replace(cfg, version=_gd_version_of(cfg.version))
-    Xs, ys, mask = dataset.gd_view(cfg.version, cfg.frac_bits, cfg.x8_frac)
-    local = _grad_kernel(pim, cfg)
-    prepare, update = make_gd_step_fns(base_cfg)
-
-    w = jnp.zeros(nf, jnp.float32)
-    b = jnp.float32(0.0)
-    s = jnp.float32(cfg.lr * (1.0 / n))
-    history = []
-    it_done = 0
-    if state is not None:
-        arrays, meta = state["arrays"], state["meta"]
-        w = jnp.asarray(arrays["w"], jnp.float32)
-        b = jnp.asarray(arrays["b"], jnp.float32)
-        s = jnp.asarray(arrays["s"], jnp.float32)
-        it_done = int(meta["iters"])
-        history = [tuple(h) for h in meta.get("history", [])]
-
-    def record(it, wv, bv):
-        if cfg.record_every and (it % cfg.record_every == 0
-                                 or it == cfg.n_iters):
-            metric = None
-            if eval_fn:
-                wh, bh = pim.read((wv, bv))
-                metric = eval_fn(wh, float(bh))
-            history.append((it, metric))
-
-    def _make_snapshot(wv, bv, sv, it):
-        """Snapshot closure bound to one chunk boundary's carry (the
-        live carry races ahead of drained boundaries when pipelined —
-        DESIGN.md §14.1)."""
-        def _snap():
-            wh, bh, sh = pim.read((wv, bv, sv))
-            return {"arrays": {"w": np.asarray(wh, np.float32),
-                               "b": np.asarray(bh, np.float32),
-                               "s": np.asarray(sh, np.float32)},
-                    "meta": {"iters": int(it),
-                             "history": [[int(i),
-                                          None if m is None else float(m)]
-                                         for i, m in history]}}
-        return _snap
-
-    def _snapshot():
-        return _make_snapshot(w, b, s, it_done)()
-
-    if cfg.fuse_steps > 1:
-        program = pim.step_program(
-            local, prepare, update,
-            name=(f"log.step/{grad_kernel_name(cfg, _exact_sigmoid(pim, cfg))}"
-                  f"/lr{cfg.lr}/n{n}"))
-        # double-buffered chunk pipeline — see linreg.fit_steps
-        pipe = ChunkPipeline(program, max(1, int(cfg.pipeline_depth)))
-
-        def _drain(bnd):
-            nonlocal it_done
-            it_done = bnd.tag
-            bw, bb, bs = bnd.carry
-            record(it_done, bw, bb)
-            return ChunkTick(bnd.k, _make_snapshot(bw, bb, bs, it_done))
-
-        it_disp = it_done
-        for k in chunk_schedule(cfg.n_iters, cfg.fuse_steps,
-                                cfg.record_every, start=it_done):
-            it_disp += k
-            (w, b, s), drained = pipe.dispatch((w, b, s), (Xs, ys, mask),
-                                               k, tag=it_disp)
-            for bnd in drained:
-                yield _drain(bnd)
-        for bnd in pipe.flush():
-            yield _drain(bnd)
-    else:
-        for it in range(it_done, cfg.n_iters):
-            wq, bq = pim.broadcast(prepare((w, b, s)))
-            partial = pim.map_reduce(local, (Xs, ys, mask), (wq, bq))
-            (w, b, s), _ = update((w, b, s), partial)
-            it_done = it + 1
-            record(it_done, w, b)
-            yield ChunkTick(1, _snapshot)
-    w, b = pim.read((w, b))
-    return GdResult(w=np.asarray(w, np.float32), b=float(b),
-                    history=history, n_iters=cfg.n_iters)
+    """Generator form of the LOG fit: the shared GD driver
+    (:func:`repro.core.linreg.fit_steps`) run with LOG's record."""
+    return linreg.fit_steps(dataset, cfg or LogRegConfig(), eval_fn,
+                            state=state, family=LOG)
 
 
 def fit(dataset, cfg: Optional[LogRegConfig] = None,

@@ -18,14 +18,14 @@ supervised retry, and a killed queue restarts from its durable
 from .allocator import (DEFAULT_RANK_SIZE, PLACEMENT_POLICIES, BankAllocator,
                         BankLease, FragmentationStats, PimSlice,
                         default_rank_size)
-from .gang import FUSABLE_WORKLOADS, FusedGdSweep, fuse_key, plan_fusion
+from .gang import FusedGdSweep, fuse_key, plan_fusion
 from .manifest import (dataset_shape, job_report, load_manifest,
                        run_manifest, serve_manifests, submit_manifest)
 from .scheduler import JobHandle, JobState, PimScheduler, SloViolation
 
 __all__ = [
     "BankAllocator", "BankLease", "DEFAULT_RANK_SIZE",
-    "FUSABLE_WORKLOADS", "FragmentationStats", "FusedGdSweep",
+    "FragmentationStats", "FusedGdSweep",
     "JobHandle", "JobState", "PLACEMENT_POLICIES", "PimScheduler",
     "PimSlice", "SloViolation", "dataset_shape",
     "default_rank_size", "fuse_key", "job_report", "load_manifest",

@@ -867,9 +867,10 @@ class StepProgram:
     is bit-identical to k unfused steps (asserted by
     tests/test_step_fusion.py).
 
-    Degradation: a non-``fusable`` strategy (HostReduce — the reduce
-    itself is a host round trip) runs the chunk as k ordinary
-    ``map_reduce`` steps with identical accounting to the unfused loop.
+    :meth:`run` alone decides how a chunk executes: as one scan, or as
+    k host-orchestrated steps (one-step chunks, non-``fusable``
+    strategies); every LIN/LOG fit and fused gang dispatches through
+    it, whatever its ``fuse_steps``.
     """
 
     def __init__(self, system: System, kernel, prepare: Callable,
@@ -950,7 +951,19 @@ class StepProgram:
         the input carry stays readable after dispatch — required when a
         :class:`ChunkPipeline` overlaps chunk N+1 with the host drain of
         boundary N (DESIGN.md §14.1).  Donation only affects buffer
-        reuse, never numerics."""
+        reuse, never numerics.
+
+        Which path a chunk takes is decided here and nowhere else.  A
+        chunk runs as k host-orchestrated steps (:meth:`_run_per_step`:
+        one broadcast, launch and sync per step) when ``k == 1`` or the
+        strategy is not ``fusable``; otherwise as one scan.  The
+        ``k == 1`` condition must hold for ``fuse_steps=1`` fits to keep
+        the per-step accounting (one launch, one sync and one broadcast
+        per step) and the ``map_reduce`` jit entries of that cadence.
+        A one-step scan with a donated carry may serve that
+        cadence faster; it may replace the per-step path only once it
+        gives the per-step results bit for bit, which on XLA CPU it does
+        not yet (LIN int32 drifts by an ulp)."""
         sharded = tuple(sharded)
         if k <= 0:
             return carry, None
@@ -958,7 +971,7 @@ class StepProgram:
         if with_xs and self.select is None:
             raise ValueError("xs given but this StepProgram has no "
                              "select hook")
-        if not self.strategy.fusable:
+        if k == 1 or not self.strategy.fusable:
             return self._run_per_step(carry, sharded, k, xs)
         # n_cores in the key: slices share the parent jit cache (vmap
         # backend) and hierarchical rank-partial shapes depend on width
@@ -985,9 +998,9 @@ class StepProgram:
         return carry, outs
 
     def _run_per_step(self, carry, sharded: tuple, k: int, xs=None):
-        """HostReduce degradation: k single steps, each with the per-step
-        broadcast + host reduce + host-visible update of the unfused
-        loop (byte/launch/sync accounting identical to not fusing)."""
+        """k host-orchestrated steps, each a broadcast of
+        ``prepare(carry)``, one ``map_reduce`` launch and sync, and an
+        eager ``update`` (the paper's per-step cadence)."""
         outs = []
         for i in range(k):
             shards = sharded

@@ -358,6 +358,30 @@ def test_fused_sweep_logreg_and_lane_cancel():
                           ref[0].result.attributes["coef_"])
 
 
+def test_fused_logreg_fp32_on_host_uses_exact_sigmoid():
+    """A fused LOG fp32 sweep on a HostSystem trains each lane with the
+    exact sigmoid its solo fit uses there: the gang's kernel carries the
+    exact flavour, and each lane matches its solo fit."""
+    from repro.api import get_workload, make_system
+    from repro.core import logreg
+    from repro.sched.gang import FusedGdSweep
+    X, y, _ = make_linear_dataset(256, 6, seed=2)
+    ds = make_system("host").put(X, (y > np.median(y)).astype(np.float32))
+    wl = get_workload("logreg")
+    lrs = [5.0, 20.0]
+    gang = FusedGdSweep(wl, [wl.spec("fp32", lr=lr, n_iters=60)
+                             for lr in lrs], ds)
+    while not gang.step():
+        pass
+    assert gang.kernel.startswith("sched.fused/K2/log.grad/fp32x/")
+    for lane, lr in enumerate(lrs):
+        solo = logreg.fit(ds, logreg.LogRegConfig(version="fp32", lr=lr,
+                                                  n_iters=60))
+        r = gang.result(lane).model
+        np.testing.assert_allclose(r.w, solo.w, rtol=1e-5, atol=1e-6)
+        assert r.b == pytest.approx(solo.b, rel=1e-5, abs=1e-6)
+
+
 @pytest.mark.slow
 def test_large_k_mixed_queue_with_backfill():
     """K=16 mixed queue on a fragmented machine drains fully; backfill

@@ -35,6 +35,14 @@ def _sched(cores=8, rank=4, **kw):
                         rank_size=rank, **kw)
 
 
+def _spool_write(path, doc):
+    """Write a manifest whole under a name the spool watcher skips, then
+    rename it: a scan never reads a half-written manifest."""
+    part = path.with_name(path.name + ".part")
+    part.write_text(json.dumps(doc))
+    os.replace(part, path)
+
+
 def _manifest_doc(n_iters=20, name="job", cores=4):
     return {
         "system": {"cores": 8, "rank_size": 4},
@@ -219,8 +227,8 @@ def test_serve_manifests_mid_flight(tmp_path, lin_data):
 
     def drop_late():
         time.sleep(0.3)
-        (spool / "m2.json").write_text(
-            json.dumps(_manifest_doc(n_iters=10, name="third")))
+        _spool_write(spool / "m2.json",
+                     _manifest_doc(n_iters=10, name="third"))
 
     t = threading.Thread(target=drop_late)
     t.start()
@@ -297,7 +305,8 @@ def test_poisson_soak_no_lost_jobs(lin_data):
 
 
 @pytest.mark.slow
-def test_cli_serve_accepts_manifest_mid_flight(tmp_path, lin_data):
+def test_cli_serve_accepts_manifest_mid_flight(tmp_path, lin_data,
+                                               monkeypatch):
     """pim_jobs --serve end to end: initial manifest drains on the
     background thread, a spooled manifest lands mid-flight, both reach
     terminal states, and the JSON report records the spool verdicts."""
@@ -309,19 +318,43 @@ def test_cli_serve_accepts_manifest_mid_flight(tmp_path, lin_data):
     spool.mkdir()
     out = tmp_path / "report.json"
 
-    def drop_late():
-        time.sleep(0.3)
-        (spool / "late.json").write_text(
-            json.dumps(_manifest_doc(n_iters=10, name="late")))
+    # the late manifest drops once the initial jobs are admitted and
+    # the spool watcher starts — an event, not a guess at the timing
+    watching = threading.Event()
+    real_serve = pim_jobs.serve_manifests
 
-    t = threading.Thread(target=drop_late)
-    t.start()
-    rc = pim_jobs.main([str(manifest), "--serve", "--spool", str(spool),
-                        "--poll-interval", "0.02",
-                        "--idle-timeout", "1.0",
-                        "--json", str(out)])
-    t.join()
-    assert rc == 0
+    def serve_and_signal(*args, **kw):
+        watching.set()
+        return real_serve(*args, **kw)
+
+    monkeypatch.setattr(pim_jobs, "serve_manifests", serve_and_signal)
+
+    def drop_late():
+        if watching.wait(timeout=60):
+            _spool_write(spool / "late.json",
+                         _manifest_doc(n_iters=10, name="late"))
+
+    dropper = threading.Thread(target=drop_late, daemon=True)
+    dropper.start()
+    outcome = {}
+
+    def run():
+        try:
+            outcome["rc"] = pim_jobs.main(
+                [str(manifest), "--serve", "--spool", str(spool),
+                 "--poll-interval", "0.02", "--idle-timeout", "1.0",
+                 "--json", str(out)])
+        except BaseException as err:  # noqa: BLE001 — re-raised below
+            outcome["error"] = err
+
+    runner = threading.Thread(target=run, daemon=True)
+    runner.start()
+    runner.join(timeout=120)        # this test's own time limit
+    assert not runner.is_alive(), "pim_jobs --serve outlived 120 s"
+    dropper.join(timeout=5)
+    if "error" in outcome:
+        raise outcome["error"]
+    assert outcome["rc"] == 0
     report = json.loads(out.read_text())
     assert {j["state"] for j in report["jobs"]} == {"done"}
     assert len(report["jobs"]) == 2

@@ -1,12 +1,17 @@
 """Step-fusion engine (core/pim.py StepProgram; DESIGN.md §9).
 
-Covers fused-vs-serial bit identity for every integer trainer version,
-float closeness for fp32/K-Means, chunk-boundary ``record_every``
+Covers fused-vs-serial bit identity for every integer trainer version
+(LIN and LOG run one driver, so the chunking cases take both), float
+closeness for fp32/K-Means, chunk-boundary ``record_every``
 equivalence, the analytic TransferStats chunk accounting (k=32 chunk ==
-ONE kernel launch — the CI assertion), HostReduce degradation, and
+ONE kernel launch — the CI assertion; a one-step chunk == one
+host-orchestrated step), HostReduce degradation, and
 scheduler integration with mixed fused/unfused jobs; the large-k and
 fused-gang cases are marked ``slow``.
 """
+import dataclasses
+
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -29,6 +34,23 @@ def lin_data():
 def log_data(lin_data):
     X, y = lin_data
     return X, (y > np.median(y)).astype(np.float32)
+
+
+@pytest.fixture(params=("linreg", "logreg"))
+def gd(request, lin_data, log_data):
+    """One GD workload of the shared driver: (registry name, trainer
+    module, config class, an integer version, its data)."""
+    if request.param == "linreg":
+        return "linreg", linreg, linreg.GdConfig, "int32", lin_data
+    return ("logreg", logreg, logreg.LogRegConfig, "int32_lut_wram",
+            log_data)
+
+
+def _gd_fit(gd, fuse, n_iters=40, eval_fn=None, **kw):
+    _, mod, cfg_cls, ver, (X, y) = gd
+    ds = PimSystem(PimConfig(n_cores=CORES)).put(X, y)
+    return mod.fit(ds, cfg_cls(version=ver, n_iters=n_iters,
+                               fuse_steps=fuse, **kw), eval_fn=eval_fn)
 
 
 def _lin_pair(X, y, ver, fuse, n_iters=40, **kw):
@@ -89,12 +111,11 @@ def test_kmeans_fused_inertia_close():
                                rtol=1e-4, atol=1e-3)
 
 
-def test_fused_partial_tail_chunk(lin_data):
+def test_fused_partial_tail_chunk(gd):
     """n_iters not divisible by fuse_steps: the tail chunk is clipped,
     total iterations exact."""
-    X, y = lin_data
-    r1, _ = _lin_pair(X, y, "int32", fuse=1, n_iters=21)
-    rk, _ = _lin_pair(X, y, "int32", fuse=8, n_iters=21)
+    r1 = _gd_fit(gd, fuse=1, n_iters=21)
+    rk = _gd_fit(gd, fuse=8, n_iters=21)
     assert np.array_equal(r1.w, rk.w) and r1.b == rk.b
 
 
@@ -102,16 +123,10 @@ def test_fused_partial_tail_chunk(lin_data):
 # record_every lands on chunk boundaries with identical history.
 # ---------------------------------------------------------------------------
 
-def test_record_every_chunk_boundary_equivalence(lin_data):
-    X, y = lin_data
-
+def test_record_every_chunk_boundary_equivalence(gd):
     def run(fuse):
-        pim = PimSystem(PimConfig(n_cores=CORES))
-        ds = pim.put(X, y)
-        cfg = linreg.GdConfig(version="int32", n_iters=25, fuse_steps=fuse,
-                              record_every=10)
-        return linreg.fit(ds, cfg,
-                          eval_fn=lambda w, b: (w.copy(), float(b)))
+        return _gd_fit(gd, fuse, n_iters=25, record_every=10,
+                       eval_fn=lambda w, b: (w.copy(), float(b)))
 
     r1, rk = run(1), run(8)
     assert [it for it, _ in r1.history] == [it for it, _ in rk.history] \
@@ -138,6 +153,42 @@ def test_k32_chunk_is_one_launch_one_sync(lin_data):
     d = pim.stats.delta(snap)
     assert d.kernel_launches == 1
     assert d.host_syncs == 1
+
+
+def test_one_step_chunk_counts_as_per_step(lin_data):
+    """Under FabricReduce, a one-step chunk — run directly, as a fused
+    fit's tail, or as every chunk of a ``fuse_steps=1`` fit — has the
+    launch, sync and byte deltas of ``StepProgram._run_per_step``."""
+    X, y = lin_data
+    pim = PimSystem(PimConfig(n_cores=CORES, reduce=ReduceVia.FABRIC))
+    ds = pim.put(X, y)
+    cfg = linreg.GdConfig(version="int32", n_iters=1)
+    view = ds.gd_view(cfg.version, cfg.frac_bits, cfg.x8_frac)
+    prepare, update = linreg.make_gd_step_fns(cfg)
+    program = pim.step_program(pim.named_kernel(*linreg.LIN.kernel(pim, cfg)),
+                               prepare, update, name="test.one_step")
+    carry = (jnp.zeros(F, jnp.float32), jnp.float32(0.0),
+             jnp.float32(0.1))
+
+    def delta(run):
+        run()                           # warm the view and jit caches
+        snap = pim.stats.snapshot()
+        run()
+        d = pim.stats.delta(snap)
+        return (d.kernel_launches, d.host_syncs, d.cpu_to_pim,
+                d.pim_to_cpu)
+
+    per_step = delta(lambda: program._run_per_step(carry, view, 1))
+    assert per_step[:2] == (1, 1)
+    assert delta(lambda: program.run(carry, view, 1)) == per_step
+    assert delta(lambda: linreg.fit(ds, cfg)) == per_step
+    # 9 iterations at fuse_steps=8: the tail chunk of one step costs
+    # what a fuse_steps=1 step does
+    fused = dataclasses.replace(cfg, n_iters=9, fuse_steps=8)
+    head = dataclasses.replace(fused, n_iters=8)
+    d9, d8 = delta(lambda: linreg.fit(ds, fused)), delta(
+        lambda: linreg.fit(ds, head))
+    assert tuple(a - b for a, b in zip(d9, d8)) == per_step
 
 
 def test_unfused_counts_one_launch_per_step(lin_data):
@@ -263,6 +314,22 @@ def test_minibatch_fuses_with_offset_scan_xs(lin_data):
     # (and syncs) where the serial SGD loop pays ten of each
     assert p1.stats.kernel_launches == 10 and p1.stats.host_syncs == 10
     assert pk.stats.kernel_launches == 2 and pk.stats.host_syncs == 2
+
+
+def test_log_minibatch_trains_on_batches(log_data):
+    """LOG honours ``minibatch`` as its registry entry promises: fused
+    equals unfused bit for bit, and both differ from full-batch GD."""
+    X, y = log_data
+
+    def fit(fuse, minibatch):
+        ds = PimSystem(PimConfig(n_cores=CORES)).put(X, y)
+        return logreg.fit(ds, logreg.LogRegConfig(
+            version="int32_lut_wram", n_iters=21, fuse_steps=fuse,
+            minibatch=minibatch, seed=3, record_every=10))
+
+    r1, rk, full = fit(1, 8), fit(8, 8), fit(1, 0)
+    assert np.array_equal(r1.w, rk.w) and r1.b == rk.b
+    assert not np.array_equal(r1.w, full.w)
 
 
 @pytest.mark.parametrize("ver", ("int32", "hyb"))
@@ -426,18 +493,22 @@ def test_lin_pipeline_eval_fn_order(lin_data):
 @pytest.mark.parametrize("ver", ("int32", "int32_lut_mram",
                                  "int32_lut_wram", "hyb_lut", "bui_lut"))
 def test_log_pipeline_bit_identical(log_data, ver):
+    """Every in-flight depth equals the unfused fit bit for bit —
+    weights, bias and recorded history."""
     X, y = log_data
     results = {}
-    for depth in (1, 2):
+    for fuse, depth in ((1, 1), (8, 1), (8, 2), (8, 3)):
         pim = PimSystem(PimConfig(n_cores=CORES))
         ds = pim.put(X, y)
         cfg = logreg.LogRegConfig(version=ver, n_iters=32,
-                                  fuse_steps=8, record_every=8,
+                                  fuse_steps=fuse, record_every=8,
                                   pipeline_depth=depth)
-        results[depth] = logreg.fit(ds, cfg)
-    assert np.array_equal(results[1].w, results[2].w)
-    assert results[1].b == results[2].b
-    assert results[1].history == results[2].history
+        results[fuse, depth] = logreg.fit(ds, cfg)
+    ref = results[1, 1]
+    for r in results.values():
+        assert np.array_equal(ref.w, r.w)
+        assert ref.b == r.b
+        assert ref.history == r.history
 
 
 def test_kmeans_pipeline_bit_identical():
@@ -504,18 +575,18 @@ def test_scheduler_gang_pipeline_bit_identical(lin_data):
         assert float(h.result.model.b) == solo.b
 
 
-def test_preempt_resume_mid_pipeline_bit_identical(lin_data):
+def test_preempt_resume_mid_pipeline_bit_identical(gd):
     """Preemption at a chunk boundary while chunks are in flight:
     the snapshot is drain-authoritative, and resuming on a fresh
     scheduler completes bit-identically to an uninterrupted fit."""
-    X, y = lin_data
-    params = dict(version="int32", n_iters=32, fuse_steps=4,
+    name, mod, cfg_cls, ver, (X, y) = gd
+    params = dict(version=ver, n_iters=32, fuse_steps=4,
                   pipeline_depth=2)
     pim = PimSystem(PimConfig(n_cores=4))
-    ref = linreg.fit(pim.put(X, y), linreg.GdConfig(**params))
+    ref = mod.fit(pim.put(X, y), cfg_cls(**params))
 
     sched = PimScheduler(PimSystem(PimConfig(n_cores=CORES)), rank_size=4)
-    h = sched.submit("linreg", (X, y), n_cores=4, **params)
+    h = sched.submit(name, (X, y), n_cores=4, **params)
     sched.step(); sched.step()
     h.preempt()
     sched.step()
